@@ -20,7 +20,6 @@ import numpy as np
 
 from benchmarks.common import timed
 from repro.configs import SHAPES, get_config
-from repro.launch import constants as C
 from repro.launch import roofline as R
 
 

@@ -31,7 +31,8 @@ def load(path):
 def fmt_row(r):
     rl = r["roofline"]
     peak = r["memory"]["peak_bytes_estimate"] / 2**30
-    fits = "OK" if peak <= C.CHIP_HBM_BYTES / 2**30 else "OVER"
+    hbm = C.peaks(r["device_kind"]).hbm_bytes
+    fits = "OK" if peak <= hbm / 2**30 else "OVER"
     return (f"| {r['arch']} | {r['shape']} | {r['mesh']} | "
             f"{rl['compute_s']*1e3:.2f} | {rl['memory_s']*1e3:.2f} | "
             f"{rl['collective_s']*1e3:.2f} | {rl['bottleneck']} | "

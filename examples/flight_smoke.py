@@ -17,6 +17,10 @@ a full pool of best-effort decoders), then:
 Run it directly::
 
     JAX_PLATFORMS=cpu python examples/flight_smoke.py --out-dir /tmp/flight
+
+This process never touches JAX: a chip belongs to one process at a
+time, so the ladder build, the gateway and each replay run in children
+of their own.
 """
 import argparse
 import json
@@ -67,14 +71,22 @@ def generate(port: int, prompt, max_new: int, priority: str) -> dict:
         return json.load(resp)
 
 
+# saves the 3-rung uniform ladder the recorded engine serves with
+LADDER_SCRIPT = """
+import sys
+from repro.configs import get_config, reduced
+from repro.models import api
+from repro.sparsity import PolicyLadder
+cfg = reduced(get_config("llama31_8b"))
+params = api.init_model(cfg, 0)
+PolicyLadder.uniform(params, cfg, [0.0, 0.5, 0.7]).save(sys.argv[1])
+"""
+
+
 def build_ladder(path: str) -> None:
-    """Save the 3-rung uniform ladder the recorded engine serves with."""
-    from repro.configs import get_config, reduced
-    from repro.models import api
-    from repro.sparsity import PolicyLadder
-    cfg = reduced(get_config("llama31_8b"))
-    params = api.init_model(cfg, 0)
-    PolicyLadder.uniform(params, cfg, [0.0, 0.5, 0.7]).save(path)
+    """Build the ladder artifact in a child process."""
+    subprocess.run([sys.executable, "-c", LADDER_SCRIPT, path], check=True,
+                   timeout=STARTUP_TIMEOUT_S)
     print(f"ladder artifact at {path}")
 
 
@@ -90,8 +102,9 @@ def main() -> None:
 
     port = free_port()
     proc = subprocess.Popen(
-        [sys.executable, "-m", "repro.launch.serve", "--gateway",
-         "--gateway-port", str(port), "--max-queue", "8", "--preemption",
+        [sys.executable, "-m", "repro.launch.serve", "--reduced",
+         "--gateway", "--gateway-port", str(port), "--max-queue", "8",
+         "--preemption",
          "--prompt-len", "16", "--gen", "1024", "--batch", "2", "--chunk", "8",
          "--ladder", ladder, "--slo-tpot-p95", "1e-9",
          "--flight-record", recording, "--flight-ring", "32768",

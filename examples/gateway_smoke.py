@@ -110,8 +110,9 @@ def scrape_metrics(port: int) -> None:
 def main() -> None:
     port = free_port()
     proc = subprocess.Popen(
-        [sys.executable, "-m", "repro.launch.serve", "--gateway",
-         "--gateway-port", str(port), "--max-queue", "8", "--preemption",
+        [sys.executable, "-m", "repro.launch.serve", "--reduced",
+         "--gateway", "--gateway-port", str(port), "--max-queue", "8",
+         "--preemption",
          "--prompt-len", "16", "--gen", "8", "--batch", "2",
          "--chunk", "8"])
     try:

@@ -38,7 +38,7 @@ import functools
 import itertools
 import os
 import warnings
-from typing import List
+from typing import List, Optional
 
 from repro.analysis.findings import Finding
 from repro.analysis.registry import GlobalPass, register
@@ -342,10 +342,28 @@ class JitStaticArgsPass(GlobalPass):
         return out
 
 
+def tiling_violation(block) -> Optional[str]:
+    """The TPU block-shape rule for one ``BlockPlan``: each of the
+    block's last two dims must be a multiple of (SUBLANE, LANE) — LANE
+    alone for a 1-D block — or span the whole array dim.  Returns what
+    is wrong, or None."""
+    from repro.kernels import sparse_matmul as K
+    dims, padded = block.dims, block.padded
+    aligns = (K.SUBLANE, K.LANE)[-len(dims):]
+    for d, pad, align in zip(dims[-2:], padded[-2:], aligns):
+        if d % align and d != pad:
+            return (f"block {block.block} over array {padded}: dim {d} "
+                    f"is neither a multiple of {align} nor the array's "
+                    f"{pad}")
+    return None
+
+
 @register
 class PallasBlockSpecPass(GlobalPass):
     """Pallas kernel launch contracts: index maps in bounds, tiles
-    divide padded dims, VMEM working set under budget.
+    divide padded dims, every block meets the TPU tiling rule (last two
+    dims divisible by (8, 128) or equal to the array's), VMEM working
+    set under budget.
 
     Sweeps the kernel plans (``kernels.sparse_matmul.shared_plan`` /
     ``per_seq_plan`` / ``score_mask_plan`` — the same objects the
@@ -391,6 +409,15 @@ class PallasBlockSpecPass(GlobalPass):
                                  "[tile/2, tile] or pad to a multiple) "
                                  "is broken"),
                         snippet=f"{plan.kernel} tiles {plan.tiles}"))
+            for b in plan.blocks:
+                bad = tiling_violation(b)
+                if bad:
+                    findings.append(Finding(
+                        rule=self.rule, path=self._REL, line=line,
+                        message=(f"{plan.kernel}: operand {b.name} {bad} "
+                                 "— Mosaic refuses the launch (interpret "
+                                 "mode hides it)"),
+                        snippet=f"{plan.kernel}/{b.name} tiling"))
             if plan.vmem_bytes() > K.VMEM_BYTES:
                 findings.append(Finding(
                     rule=self.rule, path=self._REL, line=line,
@@ -408,7 +435,7 @@ class PallasBlockSpecPass(GlobalPass):
                     for b in plan.blocks:
                         origin = b.index_map(*point, idx)
                         for d, (o, blk_d, pad_d) in enumerate(
-                                zip(origin, b.block, b.padded)):
+                                zip(origin, b.dims, b.padded)):
                             if o < 0 or (int(o) + 1) * blk_d > pad_d:
                                 findings.append(Finding(
                                     rule=self.rule, path=self._REL,
@@ -454,20 +481,23 @@ class PallasBlockSpecPass(GlobalPass):
                     snippet=f"channel_plan({n})"))
 
         # _fit_tile postconditions over a dense sweep: result divides the
-        # dim (or signals the pad path by returning `want` verbatim) and
-        # never degrades below want/2
+        # dim (or signals the pad path by returning `want` verbatim),
+        # never degrades below want/2, and is aligned or the whole dim
         fit_line = _line_of(repo_root, self._REL, "def _fit_tile")
         for size in range(1, 600):
-            for want in (8, 128, 256):
-                t = K._fit_tile(size, want)
+            for want, align in ((8, K.SUBLANE), (128, K.LANE),
+                                (256, K.LANE)):
+                t = K._fit_tile(size, want, align)
                 eff_want = min(want, size)
                 ok = (1 <= t <= eff_want and 2 * t >= eff_want
-                      and (size % t == 0 or t == eff_want))
+                      and (size % t == 0 or t == eff_want)
+                      and (t % align == 0 or t == size))
                 if not ok:
                     findings.append(Finding(
                         rule=self.rule, path=self._REL, line=fit_line,
-                        message=(f"_fit_tile({size}, {want}) = {t} breaks "
-                                 "the contract: divisor in [want/2, want] "
-                                 "or want (pad path)"),
+                        message=(f"_fit_tile({size}, {want}, {align}) = {t} "
+                                 "breaks the contract: the whole dim, an "
+                                 "aligned divisor in [want/2, want], or "
+                                 "want (pad path)"),
                         snippet=f"_fit_tile({size},{want})={t}"))
         return findings

@@ -7,6 +7,7 @@ from repro.configs.base import (
     get_config,
     reduced,
     runnable_cells,
+    serving_config,
 )
 
 __all__ = [
@@ -18,4 +19,5 @@ __all__ = [
     "get_config",
     "reduced",
     "runnable_cells",
+    "serving_config",
 ]

@@ -143,6 +143,23 @@ def get_config(name: str) -> ModelConfig:
     return mod.CONFIG
 
 
+def serving_config(arch: str, *, tiny: bool = False,
+                   layers: int = 0) -> ModelConfig:
+    """The config the serve CLI builds and a flight replay rebuilds:
+    ``arch``'s published config (``tiny``: its :func:`reduced` form),
+    with depth cut to the first ``layers`` layers (0 keeps them all).
+    The cut leaves every width as published."""
+    cfg = get_config(arch)
+    if tiny:
+        cfg = reduced(cfg)
+    if layers:
+        if not 0 < layers <= cfg.num_layers:
+            raise ValueError(f"layers must be in [1, {cfg.num_layers}] "
+                             f"for {cfg.name}, got {layers}")
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    return cfg
+
+
 def runnable_cells():
     """All (arch, shape) cells that the dry-run must lower, with skips
     applied per DESIGN.md SS5 (long_500k only for subquadratic archs)."""

@@ -55,7 +55,11 @@ def abstract_sp(cfg: ModelConfig):
 def default_sp_stacked(params, cfg: ModelConfig, keep_frac: float = 1.0,
                        alpha: float = 1.0):
     """Concrete stacked sp tree from model weights: g = column norms,
-    uniform alpha/keep (tau unused by the top-k serving backends)."""
+    uniform alpha/keep.  tau = -inf keeps every channel through the
+    per-channel threshold (Eq. 5) of the ``mask`` and ``pallas``
+    backends, so the keep budget alone sets the sparsity — a +inf tau
+    would zero every projection input there.  The top-k backends never
+    read tau."""
     groups = []
     for gi, (pattern, _reps) in enumerate(cfg.layer_groups()):
         gp = params["groups"][gi]
@@ -76,7 +80,7 @@ def default_sp_stacked(params, cfg: ModelConfig, keep_frac: float = 1.0,
                     ones = jnp.ones((v.shape[0],), jnp.float32)
                     out[k] = {"g": g,
                               "alpha": ones * alpha,
-                              "tau": ones * jnp.inf,
+                              "tau": ones * -jnp.inf,
                               "keep_frac": ones * keep_frac}
             return out
 

@@ -32,6 +32,12 @@ DEFAULT_BLK = 128      # channel-block (lane) size
 DEFAULT_MT = 256       # output tile
 DEFAULT_BT = 8         # batch tile
 
+# TPU block-shape rule: a block's last two dims must be divisible by
+# (SUBLANE, LANE) or equal the array's dims (Mosaic refuses the launch
+# otherwise; interpret mode accepts anything)
+SUBLANE = 8
+LANE = 128
+
 # Per-core VMEM (TPU on-chip vector memory, ~16 MB/core).  Every
 # kernel's working set — all live operand/output blocks, double-buffered
 # for the DMA pipeline — must fit under this or the launch fails at
@@ -45,17 +51,24 @@ class BlockPlan:
     """One operand/output of a kernel launch: its BlockSpec geometry in
     checkable form.  ``index_map`` is the exact callable handed to
     ``pl.BlockSpec`` (block-unit coordinates); ``padded`` is the array
-    shape the kernel actually launches over (after any zero-padding)."""
+    shape the kernel actually launches over (after any zero-padding).
+    A ``None`` block dim is squeezed out of the kernel's ref (one
+    element of that dim per grid step)."""
     name: str
-    block: Tuple[int, ...]
+    block: Tuple[Optional[int], ...]
     padded: Tuple[int, ...]
     index_map: Callable
     bytes_per_elem: int = 4
 
     @property
+    def dims(self) -> Tuple[int, ...]:
+        """Block extent per array dim (a squeezed dim spans 1)."""
+        return tuple(1 if d is None else d for d in self.block)
+
+    @property
     def block_bytes(self) -> int:
         n = 1
-        for d in self.block:
+        for d in self.dims:
             n *= d
         return n * self.bytes_per_elem
 
@@ -90,8 +103,8 @@ def shared_plan(B: int, n: int, m: int, kb: int, *,
     source of geometry truth — the kernel reads tiles/grid from here)."""
     blk = min(blk, n)
     assert n % blk == 0, (n, blk)
-    mt = _fit_tile(m, mt)
-    bt = _fit_tile(B, bt)
+    mt = _fit_tile(m, mt, LANE)
+    bt = _fit_tile(B, bt, SUBLANE)
     Bp = B + (-B % bt)
     mp = m + (-m % mt)
     grid = (Bp // bt, mp // mt, kb)
@@ -113,23 +126,26 @@ def shared_plan(B: int, n: int, m: int, kb: int, *,
 def per_seq_plan(B: int, n: int, m: int, kb: int, *,
                  blk: int = DEFAULT_BLK, mt: int = DEFAULT_MT,
                  x_bytes: int = 4, w_bytes: int = 4) -> KernelPlan:
-    """Launch plan for :func:`sparse_matmul_per_seq`."""
+    """Launch plan for :func:`sparse_matmul_per_seq`.  x and y launch
+    as ``(B, 1, dim)`` with the batch dim squeezed (``None``) from the
+    block, so each sequence's ``(1, blk)`` row block spans a full
+    unit dim instead of a 1-row slice of a B-row array."""
     blk = min(blk, n)
     assert n % blk == 0
-    mt = _fit_tile(m, mt)
+    mt = _fit_tile(m, mt, LANE)
     mp = m + (-m % mt)
     grid = (B, mp // mt, kb)
     return KernelPlan(
         kernel="sparse_matmul_per_seq", grid=grid,
         inputs=(
-            BlockPlan("x", (1, blk), (B, n),
-                      lambda b, j, i, idx: (b, idx[b, i]), x_bytes),
+            BlockPlan("x", (None, 1, blk), (B, 1, n),
+                      lambda b, j, i, idx: (b, 0, idx[b, i]), x_bytes),
             BlockPlan("w", (blk, mt), (n, mp),
                       lambda b, j, i, idx: (idx[b, i], j), w_bytes),
         ),
         outputs=(
-            BlockPlan("y", (1, mt), (B, mp),
-                      lambda b, j, i, idx: (b, j), 4),
+            BlockPlan("y", (None, 1, mt), (B, 1, mp),
+                      lambda b, j, i, idx: (b, 0, j), 4),
         ),
         tiles=(("m", mt, mp), ("n", blk, n)))
 
@@ -145,13 +161,19 @@ def score_mask_plan(B: int, n: int, *, blk: int = DEFAULT_BLK,
         inputs=(
             BlockPlan("x", (B, blk), (B, n),
                       lambda j, ab: (0, j), x_bytes),
-            BlockPlan("g", (blk,), (n,), lambda j, ab: (j,), 4),
+            # 2-D: a 1-D operand's XLA tiling (1024) disagrees with
+            # Mosaic's (128)
+            BlockPlan("g", (1, blk), (1, n), lambda j, ab: (0, j), 4),
             BlockPlan("rw", (B, 1), (B, 1), lambda j, ab: (0, 0), 4),
         ),
         outputs=(
             BlockPlan("xm", (B, blk), (B, n),
                       lambda j, ab: (0, j), x_bytes),
-            BlockPlan("bs", (1, 1), (nb, 1), lambda j, ab: (j, 0), 4),
+            # one lane-dense (1, 128) row per channel block, the score
+            # broadcast across it: a (1, 1) block of an (nb, 1) array
+            # breaks the TPU tiling rule
+            BlockPlan("bs", (1, LANE), (1, nb * LANE),
+                      lambda j, ab: (0, j), 4),
         ),
         tiles=(("n", blk, n),))
 
@@ -169,14 +191,19 @@ def _resolve_interpret(interpret: Optional[bool]) -> bool:
     return default_interpret() if interpret is None else interpret
 
 
-def _fit_tile(size: int, want: int) -> int:
-    """Tile for a dim of ``size``: the largest divisor of ``size`` in
-    [want/2, want] if one exists (full-width tiles, zero padding —
-    e.g. 384 under a 256 tile runs at 192), else ``want`` with the
-    caller padding up to a multiple.  Never degrades below want/2, so
-    prime dims pad instead of collapsing to 1-wide tiles."""
-    want = min(want, size)
-    for t in range(want, max(want // 2, 1) - 1, -1):
+def _fit_tile(size: int, want: int, align: int) -> int:
+    """Tile for a dim of ``size``: the whole dim when ``size <= want``,
+    else the largest multiple of ``align`` in [want/2, want] that
+    divides ``size`` (zero padding — e.g. 384 under a 256 lane tile runs
+    at 128), else ``want`` with the caller padding up to a multiple.
+    ``want`` is a multiple of ``align``; pass ``SUBLANE`` for a block's
+    second-to-last dim and ``LANE`` for its last, so every tile meets
+    the TPU rule (divisible by (8, 128) or equal to the array dim).
+    Never degrades below want/2, so prime dims pad instead of
+    collapsing to 1-wide tiles."""
+    if size <= want:
+        return size
+    for t in range(want, max(want // 2, 1) - 1, -align):
         if size % t == 0:
             return t
     return want
@@ -252,17 +279,6 @@ def sparse_matmul_shared(x, w, block_idx, *, blk: int = DEFAULT_BLK,
     return y[:B, :m] if (Bp, mp) != (B, m) else y
 
 
-def _acc_kernel_perseq(idx_ref, x_ref, w_ref, o_ref):
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    o_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                          preferred_element_type=jnp.float32)
-
-
 def sparse_matmul_per_seq(x, w, block_idx, *, blk: int = DEFAULT_BLK,
                           mt: int = DEFAULT_MT,
                           interpret: Optional[bool] = None):
@@ -284,7 +300,7 @@ def sparse_matmul_per_seq(x, w, block_idx, *, blk: int = DEFAULT_BLK,
     xs, ws = plan.inputs
     (ys,) = plan.outputs
     y = pl.pallas_call(
-        _acc_kernel_perseq,
+        _acc_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=plan.grid,
@@ -296,7 +312,7 @@ def sparse_matmul_per_seq(x, w, block_idx, *, blk: int = DEFAULT_BLK,
         ),
         out_shape=jax.ShapeDtypeStruct(ys.padded, jnp.float32),
         interpret=interpret,
-    )(block_idx, x, w)
+    )(block_idx, x[:, None], w)[:, 0]
     return y[:, :m] if mp != m else y
 
 
@@ -313,7 +329,8 @@ def _score_mask_kernel(ab_ref, x_ref, g_ref, w_ref, xm_ref, bs_ref):
     s = jnp.abs(x.astype(jnp.float32)) * jnp.power(g, alpha)
     keep = s >= tau
     xm_ref[...] = jnp.where(keep, x, jnp.zeros_like(x))
-    bs_ref[0, 0] = jnp.sum(jnp.where(keep, s, 0.0) * w_ref[...])
+    total = jnp.sum(jnp.where(keep, s, 0.0) * w_ref[...], keepdims=True)
+    bs_ref[...] = jnp.broadcast_to(total, bs_ref.shape)
 
 
 def score_mask(x, g, alpha, tau, *, blk: int = DEFAULT_BLK,
@@ -353,5 +370,5 @@ def score_mask(x, g, alpha, tau, *, blk: int = DEFAULT_BLK,
         out_shape=[jax.ShapeDtypeStruct(xo.padded, x.dtype),
                    jax.ShapeDtypeStruct(bo.padded, jnp.float32)],
         interpret=interpret,
-    )(ab, x, g, rw)
-    return xm, bs[:, 0]
+    )(ab, x, g.reshape(1, n), rw)
+    return xm, bs.reshape(nb, LANE)[:, 0]

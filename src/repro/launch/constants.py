@@ -1,6 +1,39 @@
-"""TPU v5e hardware constants for the roofline model (per chip)."""
+"""Published per-chip peaks for roofline terms, keyed by JAX's
+``device_kind``.  A device missing from the table is an error: a
+roofline against another chip's peaks is a wrong number, not a rough
+one."""
+from __future__ import annotations
 
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW_PER_LINK = 50e9        # B/s per link
-CHIP_HBM_BYTES = 16 * 1024**3
+import dataclasses
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops_bf16: float         # FLOP/s
+    hbm_bw: float             # B/s
+    ici_bw_per_link: float    # B/s per link
+    hbm_bytes: int
+    source: str
+
+
+V5E = "TPU v5 lite"           # what JAX reports for a TPU v5e chip
+
+PEAKS = {
+    V5E: ChipPeaks(
+        flops_bf16=197e12,
+        hbm_bw=819e9,
+        # 1,600 Gbit/s of interchip interconnect over four links
+        ici_bw_per_link=1600e9 / 8 / 4,
+        hbm_bytes=16 * 1024**3,
+        source='Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of ``device_kind``; ``ValueError`` when it has none."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})") from None

@@ -26,7 +26,7 @@ from repro.sparsity import SparsityPolicy
 from repro.distributed.sharding import (LOGICAL_RULES_SERVE,
                                         LOGICAL_RULES_TRAIN, param_shardings,
                                         sharding_context)
-from repro.launch import hlo_analysis, roofline as R
+from repro.launch import constants as C, hlo_analysis, roofline as R
 from repro.launch.mesh import make_production_mesh
 from repro.models import api
 from repro.models.params import logical_axes as schema_axes
@@ -106,10 +106,12 @@ def dryrun_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         hlo_bytes=float(ana["bytes"]),
         coll_bytes=R.wire_bytes(coll),
         model_flops_total=R.model_flops(cfg, shape),
+        # the host devices stand in for the v5e production mesh
+        device_kind=C.V5E,
     )
     rec = {
         "arch": arch, "shape": shape_name, "mesh": mesh_name,
-        "mode": shape.mode, "chips": chips,
+        "mode": shape.mode, "chips": chips, "device_kind": rl.device_kind,
         "sparsity": sparsity if sparse else 0.0,
         "remat": remat if shape.mode == "train" else None,
         "overrides": {k: list(map(list, v)) for k, v in (overrides or {}).items()},

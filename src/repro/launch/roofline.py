@@ -1,10 +1,12 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh) cell:
+Three terms per (arch x shape x mesh) cell, against the published peaks
+of the cell's ``device_kind`` (``repro.launch.constants``; v5e: 197 TF/s
+bf16, 819 GB/s HBM, 50 GB/s per ICI link):
 
-    compute    = HLO_FLOPs            / (chips x 197 TF/s bf16)
-    memory     = HLO_bytes            / (chips x 819 GB/s HBM)
-    collective = collective_bytes     / (chips x 50 GB/s/link ICI)
+    compute    = HLO_FLOPs            / peak FLOP/s
+    memory     = HLO_bytes            / peak HBM B/s
+    collective = collective_bytes     / peak ICI B/s per link
 
 ``compiled.cost_analysis()`` supplies FLOPs/bytes; collective bytes are
 parsed from the *optimized* HLO (``compiled.as_text()`` — the collectives
@@ -57,20 +59,12 @@ def _shape_bytes(dtype: str, dims: str) -> int:
 
 def executable_costs(compiled) -> "tuple[float, float]":
     """(FLOPs, bytes accessed) from a compiled executable's
-    ``cost_analysis()``, normalized across jax versions (some return the
-    per-device dict directly, some a one-element list) and backends
-    (missing keys read as 0 — the interpreter path reports no bytes).
-    The reusable core of the ``benchmarks/roofline_report`` extraction,
-    shared with the serving-time per-rung roofline counters
-    (``repro.obs.quality``)."""
-    try:
-        cost = compiled.cost_analysis()
-    except Exception:                     # backend without cost analysis
-        return 0.0, 0.0
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    if not isinstance(cost, dict):
-        return 0.0, 0.0
+    ``cost_analysis()`` dict.  A missing key reads as 0 (the Pallas
+    interpreter reports no bytes); a backend that cannot analyse the
+    executable raises.  The reusable core of the
+    ``benchmarks/roofline_report`` extraction, shared with the
+    serving-time per-rung roofline counters (``repro.obs.quality``)."""
+    cost = compiled.cost_analysis()
     return (float(cost.get("flops", 0.0)),
             float(cost.get("bytes accessed", 0.0)))
 
@@ -149,18 +143,23 @@ class Roofline:
     hlo_bytes: float
     coll_bytes: float          # per-device wire bytes
     model_flops_total: float
+    device_kind: str           # whose peaks bound the terms
+
+    @property
+    def peaks(self) -> C.ChipPeaks:
+        return C.peaks(self.device_kind)
 
     @property
     def compute_s(self) -> float:
-        return self.hlo_flops / C.PEAK_FLOPS_BF16
+        return self.hlo_flops / self.peaks.flops_bf16
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / C.HBM_BW
+        return self.hlo_bytes / self.peaks.hbm_bw
 
     @property
     def collective_s(self) -> float:
-        return self.coll_bytes / C.ICI_BW_PER_LINK
+        return self.coll_bytes / self.peaks.ici_bw_per_link
 
     @property
     def bottleneck(self) -> str:
@@ -182,11 +181,12 @@ class Roofline:
     def mfu(self) -> float:
         """Model-FLOPs utilization at the roofline step time."""
         return (self.model_flops_total
-                / (self.step_time_s * self.chips * C.PEAK_FLOPS_BF16))
+                / (self.step_time_s * self.chips * self.peaks.flops_bf16))
 
     def row(self) -> dict:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
+            "device_kind": self.device_kind,
             "compute_s": self.compute_s, "memory_s": self.memory_s,
             "collective_s": self.collective_s,
             "bottleneck": self.bottleneck,

@@ -4,6 +4,12 @@
     PYTHONPATH=src python -m repro.launch.serve --arch llama31_8b --reduced \
         --sparsity 0.5 --prompt-len 64 --gen 32 --batch 4
 
+``--arch`` builds the architecture at its published widths; ``--reduced``
+swaps in the tiny same-family config (CPU runs) and ``--layers N`` keeps
+only the first N layers.  The engine compiles every serving step before
+the first request (``Engine.warmup``), and the summary names the
+platform and device kind that served.
+
 Implements the paper's serving recipe: sparsify (by default) only half of
 the prefill tokens and all decode tokens (§5.1), with the per-token mask
 backend for accuracy-faithful numerics or the batched top-k backends for
@@ -80,18 +86,21 @@ the ring.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import signal
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.configs import get_config, reduced
+from repro.configs import serving_config
 from repro.core import pipeline as wis_pipeline
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import api, model as M
 from repro.sparsity import PolicyLadder, SparsityPolicy
 
@@ -156,7 +165,12 @@ def build_parser() -> argparse.ArgumentParser:
     tests can drive flag validation without spawning a process."""
     ap = argparse.ArgumentParser(prog="repro.launch.serve")
     ap.add_argument("--arch", default="llama31_8b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="tiny same-family config (CPU runs) instead of "
+                         "the published widths")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep only the first N layers (0 = all); widths "
+                         "stay as configured")
     ap.add_argument("--sparsity", type=float, default=0.5)
     ap.add_argument("--mode", default="mask",
                     choices=["mask", "topk_shared", "topk_block", "pallas"])
@@ -286,6 +300,8 @@ def validate_args(args) -> None:
     names the offending flag and what to change."""
     if not 0.0 <= args.sparsity <= 1.0:
         raise SystemExit(f"--sparsity must be in [0, 1], got {args.sparsity}")
+    if args.layers < 0:
+        raise SystemExit(f"--layers must be >= 0, got {args.layers}")
     for name in ("prompt-len", "gen", "batch", "chunk"):
         v = getattr(args, name.replace("-", "_"))
         if v <= 0:
@@ -396,14 +412,36 @@ def validate_rungs(args, num_rungs: int) -> None:
             f"loaded ladder has rungs 0..{num_rungs - 1}")
 
 
-def main():
-    args = build_parser().parse_args()
-    validate_args(args)
+@dataclasses.dataclass
+class ServeRun:
+    """What one replay-synthetic-prompts run of :func:`main` served."""
+    engine: object          # the closed repro.serving.Engine
+    init_s: float           # random weights drawn from seed 0
+    compile_s: float        # Engine built, every serving step compiled
+    serve_s: float          # first submit -> engine drained
+    tokens: int             # generated tokens over all requests
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduced(cfg)
-    params = api.init_model(cfg, 0)
+
+def _device() -> str:
+    d = jax.devices()[0]
+    return f"{d.platform} {d.device_kind}"
+
+
+def main(argv=None) -> Optional[ServeRun]:
+    args = build_parser().parse_args(argv)
+    validate_args(args)
+    use_compile_cache()
+
+    try:
+        cfg = serving_config(args.arch, tiny=args.reduced, layers=args.layers)
+    except ValueError as e:
+        raise SystemExit(f"--layers: {e}") from None
+    t0 = obs.now()
+    params = jax.block_until_ready(api.init_model(cfg, 0))
+    init_s = obs.now() - t0
+    print(f"initialized {cfg.name} ({cfg.num_layers} layers, "
+          f"d_model {cfg.d_model}) from seed 0 in {init_s:.2f}s on "
+          f"{_device()}")
     ds = SyntheticLM(DataConfig(cfg.vocab_size, args.prompt_len, args.batch))
     prompts = jnp.asarray(ds.batch(0))
 
@@ -445,9 +483,10 @@ def main():
         toks = generate(params, cfg, prompts, args.gen, sp, policy=policy)
         dt = obs.now() - t0
         n = toks.size
-        print(f"generated {n} tokens in {dt:.2f}s ({n/dt:.1f} tok/s on CPU)")
+        print(f"generated {n} tokens in {dt:.2f}s ({n/dt:.1f} tok/s on "
+              f"{_device()})")
         print("sample:", np.asarray(toks[0])[:16])
-        return
+        return None
 
     from repro.serving import (Engine, EngineConfig, SchedulerConfig,
                                SLOConfig, SpecConfig)
@@ -484,7 +523,8 @@ def main():
             capacity=args.flight_ring,
             sink=args.flight_record or None,
             dump_dir=args.flight_dump_dir,
-            meta={"arch": args.arch, "reduced": args.reduced, "seed": 0,
+            meta={"arch": args.arch, "reduced": args.reduced,
+                  "layers": args.layers, "seed": 0,
                   "ladder_path": args.ladder})
     if (args.trace_out or args.events_out or args.profile_dir
             or args.quality_probe_rate > 0 or flight is not None):
@@ -506,18 +546,22 @@ def main():
             quality=quality,
             flight=flight,
             trace_sink=args.trace_out)
+    t0 = obs.now()
     engine = Engine(params, cfg, ecfg, sp, ladder=ladder,
                     telemetry=telemetry)
+    if engine.decode_retraces_after_warmup is None:
+        engine.warmup()      # not yet warmed by the engine's own options
+    compile_s = obs.now() - t0
+    print(f"built the engine and compiled its serving steps in "
+          f"{compile_s:.2f}s on {_device()}")
     if flight is not None and hasattr(signal, "SIGUSR1"):
         # operator-triggered black-box dump: kill -USR1 <pid>
         signal.signal(signal.SIGUSR1, lambda *_: flight.dump("sigusr1"))
 
     if args.gateway:
         from repro.serving.gateway import Gateway
-        if (telemetry is not None and telemetry.profiler is not None
-                and not telemetry.profiler.start()):
-            print("profiler capture unavailable:",
-                  telemetry.profiler.error)
+        if telemetry is not None and telemetry.profiler is not None:
+            telemetry.profiler.start()
         gw = Gateway(engine, host=args.gateway_host,
                      port=args.gateway_port)
         print(f"gateway starting on http://{args.gateway_host}:"
@@ -527,7 +571,7 @@ def main():
         gw.serve_forever()
         print("gateway drained; engine stats:", engine.stats.summary())
         _report_telemetry(args, telemetry)
-        return
+        return None
 
     server = None
     if args.metrics_port:
@@ -535,10 +579,8 @@ def main():
                                    port=args.metrics_port)
         print(f"serving metrics at "
               f"http://127.0.0.1:{server.server_port}/metrics")
-    if (telemetry is not None and telemetry.profiler is not None
-            and not telemetry.profiler.start()):
-        print("profiler capture unavailable:",
-              telemetry.profiler.error)
+    if telemetry is not None and telemetry.profiler is not None:
+        telemetry.profiler.start()
     t0 = obs.now()
     for b in range(args.batch):
         engine.submit(np.asarray(prompts[b]), args.gen)
@@ -554,23 +596,26 @@ def main():
         _report_telemetry(args, telemetry)
     dt = obs.now() - t0
     n = sum(len(t) for t in out.values())
-    print(f"generated {n} tokens in {dt:.2f}s ({n/dt:.1f} tok/s on CPU)")
+    print(f"generated {n} tokens in {dt:.2f}s ({n/dt:.1f} tok/s on "
+          f"{_device()})")
+    print("retraces after warmup: decode",
+          engine.decode_retraces_after_warmup, "chunk",
+          engine.chunk_retraces_after_warmup)
     print("engine stats:", engine.stats.summary())
     print("latency:", {k: round(v, 3) for k, v in
                        latency_percentiles(engine.states.values()).items()
                        if v is not None})
     if engine.controller is not None:
         print("controller:", engine.controller.snapshot())
-        print("decode retraces after warmup:",
-              engine.decode_retraces_after_warmup)
     if engine.spec_decoder is not None:
         print("spec:", engine.spec_decoder.snapshot())
-        print("retraces after warmup: decode",
-              engine.decode_retraces_after_warmup, "verify",
+        print("verify retraces after warmup:",
               engine.verify_retraces_after_warmup)
     if engine.prefix_cache is not None:
         print("prefix cache:", engine.prefix_cache.snapshot())
     print("sample:", out[0][:16])
+    return ServeRun(engine=engine, init_s=init_s, compile_s=compile_s,
+                    serve_s=dt, tokens=n)
 
 
 def _report_telemetry(args, telemetry) -> None:
@@ -584,7 +629,7 @@ def _report_telemetry(args, telemetry) -> None:
     if telemetry.events is not None:
         print(f"logged {telemetry.events.count} events"
               + (f" to {args.events_out}" if args.events_out else ""))
-    if telemetry.profiler is not None and telemetry.profiler.error is None:
+    if telemetry.profiler is not None:
         print(f"wrote profiler trace to {args.profile_dir}")
     if telemetry.quality is not None and telemetry.quality.armed:
         q = telemetry.quality

@@ -10,6 +10,7 @@ A model's parameters are described as a pytree whose leaves are
 from __future__ import annotations
 
 import dataclasses
+import functools
 import zlib
 from typing import Optional, Tuple
 
@@ -42,7 +43,11 @@ def _path_str(path):
     return "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 2))
 def _init_leaf(spec: ParamSpec, key, default_dtype: str):
+    # jitted so the f32 draw fuses into the cast: a stacked bf16 leaf
+    # never holds a float32 copy of itself (3.5 GiB for 16 layers of
+    # Llama-3.1-8B's MLP), and the values match the eager draw bit for bit
     dtype = jnp.dtype(spec.dtype or default_dtype)
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, dtype)

@@ -107,15 +107,17 @@ class Telemetry:
 
     def close(self) -> None:
         """Flush and close every armed sink.  Idempotent: profiler stop,
-        event-log close and trace re-export all tolerate repeat calls."""
-        if self.profiler is not None:
-            self.profiler.stop()
+        event-log close and trace re-export all tolerate repeat calls.
+        The profiler stops last, so a failed capture (which raises)
+        still leaves every other sink flushed."""
         if self.tracer is not None and self.trace_sink is not None:
             self.tracer.export(self.trace_sink)
         if self.events is not None:
             self.events.close()
         if self.flight is not None:
             self.flight.close()
+        if self.profiler is not None:
+            self.profiler.stop()
 
 
 NULL_TELEMETRY = Telemetry()

@@ -21,8 +21,8 @@ rung delta at that index; otherwise: the first differing record index
 with both sides.  The CLI prints the report as JSON and exits nonzero.
 
 Engine reconstruction: the CLI rebuilds the engine from the recording's
-header — ``meta.arch``/``meta.reduced``/``meta.seed`` re-init the
-params, ``meta.ladder_path`` reloads the ladder npz (fingerprint-
+header — ``meta.arch``/``meta.reduced``/``meta.layers``/``meta.seed``
+re-init the params, ``meta.ladder_path`` reloads the ladder npz (fingerprint-
 checked against the recording), and the serialized ``ecfg`` restores
 the engine config.  Library callers with exotic setups (calibrated
 policies not load-able from an artifact) pass ``engine_factory``
@@ -113,7 +113,7 @@ def engine_factory_from_header(header: dict) -> Callable:
     construct: synthetic-init params (arch + seed) with an optional
     ladder npz; fixed-policy engines must prefill/decode dense (a
     calibrated non-dense fixed policy needs a caller factory)."""
-    from repro.configs import get_config, reduced
+    from repro.configs import serving_config
     from repro.models import api
     from repro.serving.controller import SLOConfig
     from repro.serving.engine import Engine, EngineConfig
@@ -127,9 +127,8 @@ def engine_factory_from_header(header: dict) -> Callable:
             "recording header has no meta.arch — re-record with "
             "reconstruction metadata, or call replay() with an explicit "
             "engine_factory")
-    cfg = get_config(meta["arch"])
-    if meta.get("reduced", True):
-        cfg = reduced(cfg)
+    cfg = serving_config(meta["arch"], tiny=meta.get("reduced", True),
+                         layers=meta.get("layers", 0))
     params = api.init_model(cfg, meta.get("seed", 0))
 
     ladder = None

@@ -344,8 +344,14 @@ def engine_registry(engine) -> MetricsRegistry:
             g(f"repro_quality_roofline_bytes_{phase}_rung{r}",
               cost["bytes"],
               f"executable bytes accessed, {phase} at rung {r}")
+        # utilization only where the device has published peaks
+        import jax
+        from repro.launch.constants import PEAKS
+        kind = jax.devices()[0].device_kind
         step_mean = s.decode_step_hist.mean if s.decode_step_hist else 0.0
-        for r, util in sorted(q.decode_utilization(step_mean).items()):
+        util_by_rung = (q.decode_utilization(step_mean, kind)
+                        if kind in PEAKS else {})
+        for r, util in sorted(util_by_rung.items()):
             g(f"repro_quality_decode_utilization_rung{r}", util,
               f"roofline step time over measured mean decode step, "
               f"rung {r}")

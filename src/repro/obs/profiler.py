@@ -14,14 +14,13 @@ Two layers, both opt-in:
 
 * :class:`ProfilerSession` — an explicit capture window around
   ``jax.profiler.start_trace``/``stop_trace`` writing a TensorBoard-
-  loadable profile to a directory.  Wrapped defensively: profile
-  capture depends on optional runtime pieces (libtpu / profiler plugin),
-  and a missing one must degrade to a warning, not kill serving.
+  loadable profile to a directory.  A capture that was asked for and
+  fails raises: a run that claims a trace it does not have would be
+  read as one that has it.
 """
 from __future__ import annotations
 
 import contextlib
-from typing import Optional
 
 NULL_CONTEXT = contextlib.nullcontext()
 
@@ -35,38 +34,26 @@ def annotation(name: str):
 class ProfilerSession:
     """Opt-in profiler capture window writing to ``out_dir``.
 
-    ``start()``/``stop()`` are idempotent and swallow profiler-backend
-    errors (recorded on ``.error``) — telemetry must never take down the
-    engine it observes."""
+    ``start()``/``stop()`` are idempotent; a profiler-backend error
+    propagates."""
 
     def __init__(self, out_dir: str):
         self.out_dir = out_dir
         self.active = False
-        self.error: Optional[str] = None
 
-    def start(self) -> bool:
+    def start(self) -> None:
         if self.active:
-            return True
-        try:
-            import jax.profiler
-            jax.profiler.start_trace(self.out_dir)
-        except Exception as e:                      # noqa: BLE001
-            self.error = f"start_trace failed: {e}"
-            return False
+            return
+        import jax.profiler
+        jax.profiler.start_trace(self.out_dir)
         self.active = True
-        return True
 
-    def stop(self) -> bool:
+    def stop(self) -> None:
         if not self.active:
-            return False
+            return
         self.active = False
-        try:
-            import jax.profiler
-            jax.profiler.stop_trace()
-        except Exception as e:                      # noqa: BLE001
-            self.error = f"stop_trace failed: {e}"
-            return False
-        return True
+        import jax.profiler
+        jax.profiler.stop_trace()
 
     def __enter__(self):
         self.start()

@@ -587,21 +587,25 @@ class QualityMonitor:
             return None
         return float(np.mean(self.recon_baseline[rung]))
 
-    def decode_utilization(self, measured_step_s: float) -> Dict[int, float]:
+    def decode_utilization(self, measured_step_s: float,
+                           device_kind: str) -> Dict[int, float]:
         """Per-rung achieved-vs-roofline decode utilization: the
         executable's roofline step time (max of compute and memory
-        terms) over the measured mean decode step latency.  One measured
-        mean covers all rungs — a per-rung latency split would need
-        per-rung timing state the hot path deliberately doesn't keep."""
+        terms, at ``device_kind``'s published peaks — an unknown kind
+        raises) over the measured mean decode step latency.  One
+        measured mean covers all rungs — a per-rung latency split would
+        need per-rung timing state the hot path deliberately doesn't
+        keep."""
         from repro.launch import constants as C
+        peaks = C.peaks(device_kind)
         out: Dict[int, float] = {}
         if measured_step_s <= 0:
             return out
         for (phase, r), cost in self.roofline.items():
             if phase != "decode":
                 continue
-            ideal = max(cost["flops"] / C.PEAK_FLOPS_BF16,
-                        cost["bytes"] / C.HBM_BW)
+            ideal = max(cost["flops"] / peaks.flops_bf16,
+                        cost["bytes"] / peaks.hbm_bw)
             out[r] = ideal / measured_step_s
         return out
 

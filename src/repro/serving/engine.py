@@ -588,6 +588,15 @@ class Engine:
         return self._decode_traces - self._warm_traces[0]
 
     @property
+    def chunk_retraces_after_warmup(self) -> Optional[int]:
+        """Chunked-prefill (re)traces since :meth:`warmup`; None before
+        warmup.  Stays 0: warmup compiles every rung's dense and sparse
+        prefill phase."""
+        if self._warm_traces is None:
+            return None
+        return self._chunk_traces - self._warm_traces[1]
+
+    @property
     def verify_retraces_after_warmup(self) -> Optional[int]:
         """Spec verify (re)traces since :meth:`warmup`; None before warmup
         or without spec decoding.  Stays 0 across gamma switches — every

@@ -7,6 +7,7 @@ The corpus test is *exact*: the passes must flag every line marked
 else — over-flagging is a failure just like under-flagging, because a
 noisy linter gets baselined into oblivion.
 """
+import dataclasses
 import json
 import os
 import re
@@ -258,7 +259,7 @@ def test_pallas_pass_catches_collapsed_tiles(monkeypatch):
     from repro.analysis.registry import global_passes
     from repro.kernels import sparse_matmul as K
 
-    def collapsing_fit(size, want):
+    def collapsing_fit(size, want, align):
         want = min(want, size)
         t = want
         while size % t:
@@ -268,6 +269,46 @@ def test_pallas_pass_catches_collapsed_tiles(monkeypatch):
     monkeypatch.setattr(K, "_fit_tile", collapsing_fit)
     findings = global_passes()["pallas-blockspec"].run(REPO)
     assert any("_fit_tile" in f.snippet for f in findings), findings
+
+
+def test_pallas_pass_catches_unaligned_blocks(monkeypatch):
+    """Re-introduce score_mask's old block-score output — a (1, 1) block
+    of an (nb, 1) array, which Mosaic refuses — and the pass must flag
+    the tiling rule the TPU compiler enforces."""
+    from repro.analysis.registry import global_passes
+    from repro.kernels import sparse_matmul as K
+    real_plan = K.score_mask_plan
+
+    def old_plan(B, n, *, blk=128, x_bytes=4):
+        plan = real_plan(B, n, blk=blk, x_bytes=x_bytes)
+        nb = n // min(blk, n)
+        xm, _ = plan.outputs
+        bs = K.BlockPlan("bs", (1, 1), (nb, 1), lambda j, ab: (j, 0), 4)
+        return dataclasses.replace(plan, outputs=(xm, bs))
+
+    monkeypatch.setattr(K, "score_mask_plan", old_plan)
+    findings = global_passes()["pallas-blockspec"].run(REPO)
+    assert any(f.snippet == "score_mask/bs tiling" for f in findings), \
+        findings
+
+
+@pytest.mark.parametrize("block,padded,ok", [
+    ((1, 1), (32, 1), False),           # old score_mask block scores
+    ((1, 128), (16, 4096), False),      # old per-seq row block, B > 1
+    ((6, 256), (12, 4096), False),      # old batch-12 tile of 6
+    ((8, 192), (8, 384), False),        # old lane tile of 192 for m=384
+    ((1, 128), (1, 4096), True),        # unit dim spans the array
+    ((None, 1, 128), (16, 1, 4096), True),
+    ((12, 64), (12, 64), True),         # whole array
+    ((16, 128), (32, 4096), True),
+    ((128,), (4096,), True),
+    ((64,), (4096,), False),
+])
+def test_tiling_rule_cases(block, padded, ok):
+    from repro.analysis.jaxpr_passes import tiling_violation
+    from repro.kernels import sparse_matmul as K
+    b = K.BlockPlan("op", block, padded, lambda *a: (0,) * len(block))
+    assert (tiling_violation(b) is None) == ok
 
 
 def test_static_args_pass_catches_unhashable_policy():

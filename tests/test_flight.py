@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.configs import get_config, reduced
+from repro.configs import get_config, reduced, serving_config
 from repro.data import DataConfig, SyntheticLM
 from repro.models import api
 from repro.obs import NULL_TELEMETRY, ReplayClock, ReplayDivergence, Telemetry
@@ -172,6 +172,26 @@ def test_header_reconstruction_replays_without_factory(model, ladder,
             eng.step()
     report = flight_replay.replay(sink)
     assert report.ok, report.failures
+
+
+def test_header_reconstruction_keeps_the_depth_cut(tmp_path):
+    """A run served at a cut depth (``serve --layers N``) records N in
+    its header, and the replay rebuilds that depth: a full-depth rebuild
+    would diverge on the first token."""
+    cfg = serving_config("llama31_8b", tiny=True, layers=1)
+    params = api.init_model(cfg, 0)
+    sink = str(tmp_path / "cut.jsonl")
+    fr = FlightRecorder(sink=sink, meta={
+        "arch": "llama31_8b", "reduced": True, "layers": 1, "seed": 0})
+    with Engine(params, cfg, EngineConfig(
+            max_slots=1, max_len=48, prefill_chunk=8),
+            telemetry=Telemetry(flight=fr)) as eng:
+        eng.submit(_prompts(cfg, 1, 20)[0], 8)
+        while eng.scheduler.has_work():
+            eng.step()
+    report = flight_replay.replay(sink)
+    assert report.ok, report.failures
+    assert report.tokens == 8
 
 
 def test_spec_round_replays_bit_identical(model, ladder, tmp_path):

@@ -1,5 +1,6 @@
 """Gateway HTTP/SSE front door, engine lifecycle (close / reset_ids /
 context manager), and serve-CLI flag validation."""
+import dataclasses
 import http.client
 import json
 import socket
@@ -10,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.configs import get_config, reduced
+from repro.configs import get_config, reduced, serving_config
 from repro.data import DataConfig, SyntheticLM
 from repro.launch.serve import build_parser, validate_args, validate_rungs
 from repro.models import api
@@ -337,6 +338,7 @@ def test_reset_ids_gives_fresh_namespace(model):
     (["--flight-ring", "1024"], "needs --flight-record"),
     (["--flight-dump-dir", "/tmp"], "needs --flight-record"),
     (["--flight-record", "f.jsonl", "--legacy"], "engine path"),
+    (["--layers", "-1"], "--layers"),
 ])
 def test_serve_cli_rejects_bad_flags(argv, msg):
     args = build_parser().parse_args(argv)
@@ -366,6 +368,21 @@ def test_serve_cli_accepts_good_flags(tmp_path):
                   "--flight-dump-dir", str(tmp_path)],
                  ["--gateway", "--flight-record", "f.jsonl"]):
         validate_args(build_parser().parse_args(argv))
+
+
+def test_serve_cli_builds_published_widths_unless_reduced():
+    """``--reduced`` is opt-in and ``--layers`` cuts depth only: the
+    default run is the published config, and a cut keeps every width."""
+    args = build_parser().parse_args([])
+    assert (args.reduced, args.layers) == (False, 0)
+    full = get_config("llama31_8b")
+    assert serving_config(args.arch, tiny=args.reduced,
+                          layers=args.layers) == full
+    cut = serving_config("llama31_8b", layers=12)
+    assert cut == dataclasses.replace(full, num_layers=12)
+    assert serving_config("llama31_8b", tiny=True) == reduced(full)
+    with pytest.raises(ValueError, match=r"layers must be in \[1, 32\]"):
+        serving_config("llama31_8b", layers=33)
 
 
 def test_serve_cli_rung_range_checked_against_ladder():

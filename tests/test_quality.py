@@ -4,6 +4,8 @@ attribution, roofline counters, and the quality-aware controller hint."""
 import dataclasses
 import json
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -129,9 +131,11 @@ def test_probe_parity_dense_agreement_and_roofline(model, ladder):
     assert ("decode", 0) in q.roofline and ("decode", 1) in q.roofline
     assert all(c["flops"] >= 0 and c["bytes"] >= 0
                for c in q.roofline.values())
-    util = q.decode_utilization(1e-3)
+    util = q.decode_utilization(1e-3, "TPU v5 lite")
     assert set(util) == {0, 1} and all(u >= 0 for u in util.values())
-    assert q.decode_utilization(0.0) == {}
+    assert q.decode_utilization(0.0, "TPU v5 lite") == {}
+    with pytest.raises(ValueError, match="no published peaks"):
+        q.decode_utilization(1e-3, "cpu")
 
 
 def test_sparse_rung_recon_baseline_and_exposition(model, ladder):
@@ -140,6 +144,14 @@ def test_sparse_rung_recon_baseline_and_exposition(model, ladder):
     ratio, and the repro_quality_* families reach the exposition."""
     params, cfg = model
     L = cfg.num_layers
+    # the uniform ladder's tau = -inf keeps every channel through the
+    # recon pass's Eq. 5 mask (live error exactly 0); a rung-1 threshold
+    # that no channel reaches stands in for a calibrated one
+    sps = list(ladder.sps)
+    sps[1] = jax.tree_util.tree_map_with_path(
+        lambda path, a: jnp.full_like(a, jnp.inf)
+        if path[-1].key == "tau" else a, sps[1])
+    ladder = dataclasses.replace(ladder, sps=tuple(sps))
     with_base = dataclasses.replace(ladder, baselines={
         "recon": np.full((2, L), 1e-8),
         "channels": tuple(tuple(np.arange(4, dtype=np.int64)

@@ -10,12 +10,6 @@ from repro.launch import hlo_analysis as H
 from repro.launch import roofline as R
 
 
-def _cost(compiled) -> dict:
-    """compiled.cost_analysis() returns a per-device list on older jax."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def test_analyzer_matches_cost_analysis_unrolled():
     x = jax.ShapeDtypeStruct((64, 256), jnp.float32)
     ws = jax.ShapeDtypeStruct((4, 256, 256), jnp.float32)
@@ -31,7 +25,7 @@ def test_analyzer_matches_cost_analysis_unrolled():
     expected = 2 * 64 * 256 * 256 * 4
     assert a["flops"] == expected
     # XLA agrees on scan-free modules (upto convert/noise ops)
-    assert abs(a["flops"] - _cost(c)["flops"]) / expected < 0.2
+    assert abs(a["flops"] - c.cost_analysis()["flops"]) / expected < 0.2
 
 
 def test_analyzer_scales_scan_by_trip_count():
@@ -48,7 +42,7 @@ def test_analyzer_scales_scan_by_trip_count():
     expected = 2 * 64 * 256 * 256 * 12
     assert a["flops"] == expected
     # ...which is what cost_analysis misses (counts the body once)
-    assert _cost(c)["flops"] < expected / 6
+    assert c.cost_analysis()["flops"] < expected / 6
 
 
 def test_collective_regex():
@@ -86,12 +80,29 @@ def test_model_flops_moe_uses_active_params():
 def test_roofline_terms_and_bottleneck():
     rl = R.Roofline("a", "s", "single", 256, hlo_flops=197e12,
                     hlo_bytes=819e9 * 2, coll_bytes=50e9 * 0.5,
-                    model_flops_total=197e12 * 256 * 0.5)
+                    model_flops_total=197e12 * 256 * 0.5,
+                    device_kind="TPU v5 lite")
     assert abs(rl.compute_s - 1.0) < 1e-9
     assert abs(rl.memory_s - 2.0) < 1e-9
     assert abs(rl.collective_s - 0.5) < 1e-9
     assert rl.bottleneck == "memory"
     assert abs(rl.mfu - 0.25) < 1e-9
+
+
+def test_roofline_refuses_unknown_device():
+    """No peaks for the device: an error, never the v5e numbers."""
+    rl = R.Roofline("a", "s", "single", 1, hlo_flops=1.0, hlo_bytes=1.0,
+                    coll_bytes=0.0, model_flops_total=1.0, device_kind="cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        rl.compute_s
+
+
+def test_executable_costs_reads_the_cost_dict():
+    c = jax.jit(lambda x: x @ x).lower(
+        jax.ShapeDtypeStruct((64, 64), jnp.float32)).compile()
+    flops, byts = R.executable_costs(c)
+    assert flops == pytest.approx(2 * 64 ** 3, rel=0.01)
+    assert byts > 0
 
 
 @pytest.mark.parametrize("shape,expected_factor", [
