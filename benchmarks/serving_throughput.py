@@ -74,7 +74,7 @@ p95 TTFT <= 0.7x the FIFO baseline's.
 ``--quality`` runs the quality-observability sweep: a ladder engine
 pinned at a sparse rung replays the trace with the
 :class:`repro.obs.QualityMonitor` off and on (shadow dense probes,
-online reconstruction error, saliency drift, roofline counters).  Hard
+online reconstruction error, saliency drift).  Hard
 gates: bit-identical tokens probes-on vs off on every rep, probes-on
 wall-clock throughput >= 97% of probes-off, zero decode AND zero
 probe/recon retraces after warmup, and the exported artifacts carry the
@@ -633,7 +633,7 @@ def run_telemetry(log=print, cfg=None, n_requests=12, rate_hz=8.0,
         rows.append((f"serving/telemetry/decode_tps/{mode}", 0.0,
                      f"{results[mode]:.1f}tok/s"))
     log(f"full-telemetry decode throughput: {ratio:.1%} of plain "
-        f"(gate >= {overhead_gate:.0%}) | {len(tel.tracer.events)} spans, "
+        f"(gate >= {overhead_gate:.0%}) | {len(tel.tracer)} spans, "
         f"{tel.events.count} events | decode retraces with annotations "
         f"{retraces}")
     rows.append(("serving/telemetry/overhead_ratio", 0.0,
@@ -683,7 +683,7 @@ def run_quality(log=print, cfg=None, budgets=(0.0, 0.5), rung=1,
     The same Poisson trace replays against a ladder engine pinned at a
     sparse rung with no quality monitor and an identical engine with the
     :class:`repro.obs.QualityMonitor` armed (shadow dense probes, online
-    reconstruction error, saliency drift, roofline counters).
+    reconstruction error, saliency drift).
 
     Hard gates: (1) bit-identical tokens probes-on vs probes-off on
     EVERY rep — the probe's dense KV writes are overwritten by the real

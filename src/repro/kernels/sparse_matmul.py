@@ -45,6 +45,13 @@ LANE = 128
 VMEM_BYTES = 16 * 1024 * 1024
 DOUBLE_BUFFER = 2      # pallas pipelines block DMA against compute
 
+# Each kernel's stable name: its ``pallas_call`` name and the
+# ``jax.named_scope`` around the call, so a profiler trace's op carries
+# it (in its framework-name stat) and a reader can find the kernel.
+SHARED_NAME = "wisparse_sparse_matmul_shared"
+PER_SEQ_NAME = "wisparse_sparse_matmul_per_seq"
+SCORE_MASK_NAME = "wisparse_score_mask"
+
 
 @dataclasses.dataclass(frozen=True)
 class BlockPlan:
@@ -262,20 +269,22 @@ def sparse_matmul_shared(x, w, block_idx, *, blk: int = DEFAULT_BLK,
 
     xs, ws = plan.inputs
     (ys,) = plan.outputs
-    y = pl.pallas_call(
-        _acc_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=plan.grid,
-            in_specs=[
-                pl.BlockSpec(xs.block, xs.index_map),
-                pl.BlockSpec(ws.block, ws.index_map),
-            ],
-            out_specs=pl.BlockSpec(ys.block, ys.index_map),
-        ),
-        out_shape=jax.ShapeDtypeStruct(ys.padded, jnp.float32),
-        interpret=interpret,
-    )(block_idx, x, w)
+    with jax.named_scope(SHARED_NAME):
+        y = pl.pallas_call(
+            _acc_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=plan.grid,
+                in_specs=[
+                    pl.BlockSpec(xs.block, xs.index_map),
+                    pl.BlockSpec(ws.block, ws.index_map),
+                ],
+                out_specs=pl.BlockSpec(ys.block, ys.index_map),
+            ),
+            out_shape=jax.ShapeDtypeStruct(ys.padded, jnp.float32),
+            interpret=interpret,
+            name=SHARED_NAME,
+        )(block_idx, x, w)
     return y[:B, :m] if (Bp, mp) != (B, m) else y
 
 
@@ -299,20 +308,22 @@ def sparse_matmul_per_seq(x, w, block_idx, *, blk: int = DEFAULT_BLK,
 
     xs, ws = plan.inputs
     (ys,) = plan.outputs
-    y = pl.pallas_call(
-        _acc_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=plan.grid,
-            in_specs=[
-                pl.BlockSpec(xs.block, xs.index_map),
-                pl.BlockSpec(ws.block, ws.index_map),
-            ],
-            out_specs=pl.BlockSpec(ys.block, ys.index_map),
-        ),
-        out_shape=jax.ShapeDtypeStruct(ys.padded, jnp.float32),
-        interpret=interpret,
-    )(block_idx, x[:, None], w)[:, 0]
+    with jax.named_scope(PER_SEQ_NAME):
+        y = pl.pallas_call(
+            _acc_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=plan.grid,
+                in_specs=[
+                    pl.BlockSpec(xs.block, xs.index_map),
+                    pl.BlockSpec(ws.block, ws.index_map),
+                ],
+                out_specs=pl.BlockSpec(ys.block, ys.index_map),
+            ),
+            out_shape=jax.ShapeDtypeStruct(ys.padded, jnp.float32),
+            interpret=interpret,
+            name=PER_SEQ_NAME,
+        )(block_idx, x[:, None], w)[:, 0]
     return y[:, :m] if mp != m else y
 
 
@@ -352,23 +363,25 @@ def score_mask(x, g, alpha, tau, *, blk: int = DEFAULT_BLK,
         rw = row_weights.reshape(B, 1).astype(jnp.float32)
     xs, gs, rs = plan.inputs
     xo, bo = plan.outputs
-    xm, bs = pl.pallas_call(
-        _score_mask_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=plan.grid,
-            in_specs=[
-                pl.BlockSpec(xs.block, xs.index_map),
-                pl.BlockSpec(gs.block, gs.index_map),
-                pl.BlockSpec(rs.block, rs.index_map),
-            ],
-            out_specs=[
-                pl.BlockSpec(xo.block, xo.index_map),
-                pl.BlockSpec(bo.block, bo.index_map),
-            ],
-        ),
-        out_shape=[jax.ShapeDtypeStruct(xo.padded, x.dtype),
-                   jax.ShapeDtypeStruct(bo.padded, jnp.float32)],
-        interpret=interpret,
-    )(ab, x, g.reshape(1, n), rw)
+    with jax.named_scope(SCORE_MASK_NAME):
+        xm, bs = pl.pallas_call(
+            _score_mask_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=plan.grid,
+                in_specs=[
+                    pl.BlockSpec(xs.block, xs.index_map),
+                    pl.BlockSpec(gs.block, gs.index_map),
+                    pl.BlockSpec(rs.block, rs.index_map),
+                ],
+                out_specs=[
+                    pl.BlockSpec(xo.block, xo.index_map),
+                    pl.BlockSpec(bo.block, bo.index_map),
+                ],
+            ),
+            out_shape=[jax.ShapeDtypeStruct(xo.padded, x.dtype),
+                       jax.ShapeDtypeStruct(bo.padded, jnp.float32)],
+            interpret=interpret,
+            name=SCORE_MASK_NAME,
+        )(ab, x, g.reshape(1, n), rw)
     return xm, bs.reshape(nb, LANE)[:, 0]

@@ -67,8 +67,8 @@ Quality monitoring: ``--quality-probe-rate R`` (R in (0, 1]) arms the
 :class:`repro.obs.QualityMonitor` — it samples that fraction of decode
 steps through a shadow dense probe (token agreement + top-k logit
 overlap vs the dense reference), measures online block reconstruction
-error against calibration baselines, watches saliency drift per
-(block, rung) and exports per-rung roofline counters.  Probes never
+error against calibration baselines and watches saliency drift per
+(block, rung).  Probes never
 alter served tokens.  ``--quality-drift-threshold`` tunes the EWMA
 saliency-overlap level below which a ``saliency_drift`` event fires.
 
@@ -250,7 +250,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--quality-probe-rate", type=float, default=0.0,
                     help="sample this fraction of decode steps through a "
                          "shadow dense probe (token agreement, recon "
-                         "error, saliency drift, roofline counters; "
+                         "error, saliency drift; "
                          "0 = off)")
     ap.add_argument("--quality-drift-threshold", type=float, default=None,
                     help="EWMA saliency-overlap level below which a "
@@ -624,7 +624,7 @@ def _report_telemetry(args, telemetry) -> None:
     if telemetry is None:
         return
     if telemetry.tracer is not None:
-        print(f"wrote {len(telemetry.tracer.events)} trace events "
+        print(f"wrote {len(telemetry.tracer)} trace events "
               f"to {args.trace_out}")
     if telemetry.events is not None:
         print(f"logged {telemetry.events.count} events"

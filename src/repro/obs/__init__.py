@@ -1,6 +1,6 @@
 """``repro.obs`` — unified, zero-overhead-when-disabled engine telemetry.
 
-Four surfaces behind one :class:`Telemetry` facade threaded through the
+The surfaces behind one :class:`Telemetry` facade threaded through the
 serving stack (``Engine(..., telemetry=...)``):
 
 * **metrics** (:mod:`repro.obs.metrics`) — Counter/Gauge/Histogram with
@@ -14,14 +14,20 @@ serving stack (``Engine(..., telemetry=...)``):
   rollbacks, compile/retrace records) with an optional JSONL sink;
 * **profiler** (:mod:`repro.obs.profiler`) — JAX dispatch annotations
   and an opt-in ``jax.profiler`` capture window;
+* **spans** (:mod:`repro.obs.spans`) — the program's own spans at every
+  boundary of an engine step (and ``python/gc`` collections) in a
+  bounded store on the monotonic clock, each also a dispatch
+  annotation, so a profiler capture's idle gaps can be put down to
+  what the host was doing;
 * **quality** (:mod:`repro.obs.quality`) — live sparsity-quality probes:
   shadow dense probes, online Eq. 6 reconstruction error vs calibration
-  baselines, saliency-drift detection, per-rung roofline counters.
+  baselines, saliency-drift detection.
 
 The default engine configuration uses :data:`NULL_TELEMETRY`: every
 surface is ``None``, every hot-path emit site is an ``is not None``
 check, and :meth:`Telemetry.annotate` returns a shared reusable null
-context — the disabled path allocates nothing.
+context — the disabled path reads no clock, stores nothing and hooks
+nothing into the garbage collector.
 
 Clock discipline: all serving timestamps come from :func:`now`
 (monotonic; :mod:`repro.obs.clock`), so spans, events, stats, and
@@ -34,7 +40,7 @@ import dataclasses
 from typing import Optional
 
 from repro.obs.clock import (SYSTEM_CLOCK, ReplayClock, ReplayDivergence,
-                             SystemClock, now, to_wall)
+                             SystemClock, now, now_ns, to_wall)
 from repro.obs.events import EventLog
 from repro.obs.flight import FlightRecorder
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
@@ -43,6 +49,7 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                validate_exposition)
 from repro.obs.profiler import NULL_CONTEXT, ProfilerSession, annotation
 from repro.obs.quality import QualityConfig, QualityMonitor
+from repro.obs.spans import SpanStore
 from repro.obs.trace import SpanTracer, validate_chrome_trace
 
 
@@ -52,10 +59,11 @@ class Telemetry:
     the all-``None`` default is :data:`NULL_TELEMETRY` and costs nothing
     on the hot path.
 
-    ``annotate_dispatch`` arms per-dispatch
-    ``jax.profiler.TraceAnnotation`` labels; ``profiler`` is an opt-in
-    capture-window session the driver starts/stops around the region it
-    wants profiled."""
+    ``annotate_dispatch`` arms the span store (:attr:`spans`) with a
+    ``jax.profiler.TraceAnnotation`` per span; a ``tracer`` alone arms
+    the store without annotations, for the tracer's engine track.
+    ``profiler`` is an opt-in capture-window session the caller
+    starts/stops around the region it wants profiled."""
 
     tracer: Optional[SpanTracer] = None
     events: Optional[EventLog] = None
@@ -76,6 +84,17 @@ class Telemetry:
     # attaches it at construction (wrapping its injected clock); None
     # keeps every emit site to an `is not None` check.
     flight: Optional[FlightRecorder] = None
+    # the program's spans (repro.obs.spans): made here when annotations
+    # or a tracer ask for them, None otherwise
+    spans: Optional[SpanStore] = dataclasses.field(default=None,
+                                                   init=False)
+
+    def __post_init__(self):
+        if self.annotate_dispatch or self.tracer is not None:
+            self.spans = SpanStore(annotate=self.annotate_dispatch)
+            self.spans.watch_gc()
+        if self.tracer is not None:
+            self.tracer.bind(self.spans)
 
     @property
     def enabled(self) -> bool:
@@ -83,12 +102,15 @@ class Telemetry:
                 or self.annotate_dispatch or self.profiler is not None
                 or self.quality is not None or self.flight is not None)
 
-    def annotate(self, name: str):
-        """Context manager for one dispatch: a profiler TraceAnnotation
-        when armed, the shared null context (no allocation) otherwise."""
-        if not self.annotate_dispatch:
+    def annotate(self, name: str, request_id: Optional[int] = None,
+                 **args):
+        """Context manager for one span of the program: a stored span
+        (and a profiler TraceAnnotation carrying ``args``) when armed,
+        the shared null context otherwise."""
+        spans = self.spans
+        if spans is None:
             return NULL_CONTEXT
-        return annotation(name)
+        return spans.span(name, request_id, args)
 
     @classmethod
     def full(cls, events_sink=None, profile_dir: Optional[str] = None,
@@ -112,6 +134,8 @@ class Telemetry:
         still leaves every other sink flushed."""
         if self.tracer is not None and self.trace_sink is not None:
             self.tracer.export(self.trace_sink)
+        if self.spans is not None:
+            self.spans.unwatch_gc()
         if self.events is not None:
             self.events.close()
         if self.flight is not None:
@@ -123,7 +147,7 @@ class Telemetry:
 NULL_TELEMETRY = Telemetry()
 
 __all__ = [
-    "Telemetry", "NULL_TELEMETRY", "now", "to_wall",
+    "Telemetry", "NULL_TELEMETRY", "now", "now_ns", "to_wall",
     "SystemClock", "SYSTEM_CLOCK", "ReplayClock", "ReplayDivergence",
     "SpanTracer", "validate_chrome_trace",
     "EventLog", "FlightRecorder",
@@ -131,5 +155,5 @@ __all__ = [
     "engine_registry", "engine_exposition", "parse_exposition",
     "validate_exposition", "serve_metrics",
     "ProfilerSession", "annotation", "NULL_CONTEXT",
-    "QualityConfig", "QualityMonitor",
+    "QualityConfig", "QualityMonitor", "SpanStore",
 ]

@@ -32,6 +32,13 @@ def now() -> float:
     return time.monotonic()
 
 
+def now_ns() -> int:
+    """:func:`now` in integer nanoseconds (the same monotonic clock): the
+    span store's stamp, read out of band of the engine's injected
+    clock so that a flight recording's clock stream never sees it."""
+    return time.monotonic_ns()
+
+
 def to_wall(t_mono: float) -> float:
     """Approximate wall-clock epoch seconds for a :func:`now` timestamp
     (human-facing logs only; durations must subtract monotonic stamps)."""

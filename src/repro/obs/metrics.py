@@ -338,23 +338,6 @@ def engine_registry(engine) -> MetricsRegistry:
                 g(f"repro_quality_recon_baseline_rung{r}", base,
                   f"calibration-time mean block reconstruction MSE, "
                   f"rung {r}")
-        for (phase, r), cost in sorted(q.roofline.items()):
-            g(f"repro_quality_roofline_flops_{phase}_rung{r}",
-              cost["flops"], f"executable FLOPs, {phase} at rung {r}")
-            g(f"repro_quality_roofline_bytes_{phase}_rung{r}",
-              cost["bytes"],
-              f"executable bytes accessed, {phase} at rung {r}")
-        # utilization only where the device has published peaks
-        import jax
-        from repro.launch.constants import PEAKS
-        kind = jax.devices()[0].device_kind
-        step_mean = s.decode_step_hist.mean if s.decode_step_hist else 0.0
-        util_by_rung = (q.decode_utilization(step_mean, kind)
-                        if kind in PEAKS else {})
-        for r, util in sorted(util_by_rung.items()):
-            g(f"repro_quality_decode_utilization_rung{r}", util,
-              f"roofline step time over measured mean decode step, "
-              f"rung {r}")
     return reg
 
 

@@ -16,19 +16,28 @@ Two layers, both opt-in:
   ``jax.profiler.start_trace``/``stop_trace`` writing a TensorBoard-
   loadable profile to a directory.  A capture that was asked for and
   fails raises: a run that claims a trace it does not have would be
-  read as one that has it.
+  read as one that has it.  Each capture opens with a ``repro/clock``
+  annotation whose ``mono_ns`` argument is the monotonic clock
+  (:func:`repro.obs.clock.now_ns`) read as it starts: the offset that
+  lays the span store and a ``SpanTracer`` export (``otherData``
+  ``mono_origin_ns``) over the capture.
 """
 from __future__ import annotations
 
 import contextlib
 
+from repro.obs.clock import now_ns
+
+CLOCK_MARK = "repro/clock"
+
 NULL_CONTEXT = contextlib.nullcontext()
 
 
-def annotation(name: str):
-    """A profiler trace annotation context for one dispatch."""
+def annotation(name: str, **args):
+    """A profiler trace annotation context for one dispatch; ``args``
+    are kept as the event's stats."""
     import jax.profiler
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 class ProfilerSession:
@@ -47,6 +56,8 @@ class ProfilerSession:
         import jax.profiler
         jax.profiler.start_trace(self.out_dir)
         self.active = True
+        with jax.profiler.TraceAnnotation(CLOCK_MARK, mono_ns=now_ns()):
+            pass
 
     def stop(self) -> None:
         if not self.active:

@@ -8,7 +8,7 @@ holds.  WiSparse's own quality machinery (Eq. 6 block reconstruction
 error, weight-aware channel saliency) runs once at calibration time and
 is never measured again, even though saliency statistics drift when the
 serving distribution stops matching the calibration set.  The
-:class:`QualityMonitor` closes that loop with four probes, all riding
+:class:`QualityMonitor` closes that loop with three probes, all riding
 the engine's existing compile-once discipline:
 
 1. **Shadow dense probes** — a configurable fraction of decode steps is
@@ -34,11 +34,10 @@ the engine's existing compile-once discipline:
    threshold emits a ``saliency_drift`` event with (block, rung)
    attribution and raises the ``pressure`` gauge the controller can read
    as an advisory de-escalation hint (``SLOConfig.quality_aware``).
-4. **Per-rung roofline counters** — at ``warmup()`` every rung's
-   decode/chunk (and spec verify) executable is AOT-lowered and its
-   ``cost_analysis()`` FLOPs/bytes captured
-   (:func:`repro.launch.roofline.executable_costs`), exported as gauges
-   plus an achieved-vs-roofline decode utilization estimate.
+
+How near a step comes to the chip's roofline is measured on the chip,
+from a profiler trace (the benchmark's ``sparse_mm_roofline`` and
+``mfu`` readers), not from a compiled program's static cost.
 
 Zero-cost when off: ``NULL_TELEMETRY.quality is None`` and the engine's
 only hot-path touch is one ``is not None`` check.  Retrace-free when on:
@@ -46,7 +45,7 @@ the probe and reconstruction executables are jitted once and precompiled
 by :meth:`attach` (called from ``Engine.warmup()``); their trace
 counters are baselined exactly like the engine's
 (``retraces_after_warmup``).  Spec engines never run the plain decode
-step, so they expose roofline counters but do not probe.
+step, so they do not probe.
 
 Module import stays light (stdlib + numpy + ``obs.metrics``); jax and
 the model stack load lazily at :meth:`attach`.
@@ -189,8 +188,8 @@ class QualityMonitor:
 
     Construct with a :class:`QualityConfig` (or kwargs), hand it to the
     engine via ``Telemetry(quality=...)``; ``Engine.warmup()`` calls
-    :meth:`attach`, which builds and precompiles the probe executables
-    and captures the roofline counters.  Until then the monitor is inert
+    :meth:`attach`, which builds and precompiles the probe executables.
+    Until then the monitor is inert
     (``armed`` is False and ``should_probe`` always says no)."""
 
     def __init__(self, cfg: Optional[QualityConfig] = None, **kw):
@@ -219,8 +218,6 @@ class QualityMonitor:
         self.recon_baseline: Optional[np.ndarray] = None   # (rungs, blocks)
         self.recon_last: Optional[np.ndarray] = None       # (blocks,)
         self.recon_ratio: Optional[float] = None
-        # roofline counters: (phase, rung) -> {"flops", "bytes"}
-        self.roofline: Dict[Tuple[str, int], Dict[str, float]] = {}
         # executables (built at attach)
         self._vstep = None
         self._rstep = None
@@ -237,15 +234,14 @@ class QualityMonitor:
     # wiring
     # ------------------------------------------------------------------
     def attach(self, engine) -> None:
-        """Build + precompile the probe executables against ``engine``
-        and capture the per-rung roofline counters.  Called from
+        """Build + precompile the probe executables against ``engine``.
+        Called from
         ``Engine.warmup()`` on an idle engine (the precompile dispatches
         write only scratch/overwritten cache positions, exactly like the
         rest of warmup)."""
         import jax
         import jax.numpy as jnp
 
-        from repro.launch.roofline import executable_costs
         from repro.models import api
         from repro.sparsity import SparsityPolicy
 
@@ -355,42 +351,6 @@ class QualityMonitor:
                         ch = np.asarray(ch, np.int64)
                         if ch.size:
                             self.saliency_ref[(r, d)] = ch
-
-        # -- per-rung roofline counters (AOT: lower + compile only; no
-        # execution, so cache donation never actually happens) ----------
-        t0 = jnp.zeros((S,), jnp.int32)
-        inactive = jnp.zeros((S,), jnp.float32)
-        C = engine.ecfg.prefill_chunk
-        for r, ((pd, _ps, dec), sp) in enumerate(
-                zip(engine._rung_phases, engine._rung_sp)):
-            compiled = engine._dstep.lower(
-                engine.params, t0, p1, engine.pool.caches, sp, inactive,
-                policy=dec).compile()
-            flops, byts = executable_costs(compiled)
-            self.roofline[("decode", r)] = {"flops": flops, "bytes": byts}
-            if engine.prefill_strategy == "chunked":
-                compiled = engine._cstep.lower(
-                    engine.params, jnp.zeros((1, C), jnp.int32),
-                    jnp.zeros((1,), jnp.int32), jnp.int32(0),
-                    engine.pool.caches, sp, jnp.zeros((C,), jnp.float32),
-                    policy=pd).compile()
-                flops, byts = executable_costs(compiled)
-                self.roofline[("chunk", r)] = {"flops": flops,
-                                               "bytes": byts}
-        if engine.spec_decoder is not None:
-            sd = engine.spec_decoder
-            _, _, ver_pol = engine._rung_phases[sd.verifier_rung]
-            ver_sp = engine._rung_sp[sd.verifier_rung]
-            for g in engine.ecfg.spec.gammas():
-                compiled = sd._vstep.lower(
-                    engine.params, jnp.zeros((S, g + 1), jnp.int32),
-                    jnp.full((S,), engine.pool_len - (g + 1), jnp.int32),
-                    engine.pool.caches, ver_sp,
-                    jnp.zeros((S, g + 1), jnp.float32),
-                    policy=ver_pol).compile()
-                flops, byts = executable_costs(compiled)
-                self.roofline[(f"verify{g}", sd.verifier_rung)] = {
-                    "flops": flops, "bytes": byts}
 
         self._warm = (self._probe_traces, self._recon_traces)
         self.armed = True
@@ -586,28 +546,6 @@ class QualityMonitor:
                 or rung >= self.recon_baseline.shape[0]:
             return None
         return float(np.mean(self.recon_baseline[rung]))
-
-    def decode_utilization(self, measured_step_s: float,
-                           device_kind: str) -> Dict[int, float]:
-        """Per-rung achieved-vs-roofline decode utilization: the
-        executable's roofline step time (max of compute and memory
-        terms, at ``device_kind``'s published peaks — an unknown kind
-        raises) over the measured mean decode step latency.  One
-        measured mean covers all rungs — a per-rung latency split would
-        need per-rung timing state the hot path deliberately doesn't
-        keep."""
-        from repro.launch import constants as C
-        peaks = C.peaks(device_kind)
-        out: Dict[int, float] = {}
-        if measured_step_s <= 0:
-            return out
-        for (phase, r), cost in self.roofline.items():
-            if phase != "decode":
-                continue
-            ideal = max(cost["flops"] / peaks.flops_bf16,
-                        cost["bytes"] / peaks.hbm_bw)
-            out[r] = ideal / measured_step_s
-        return out
 
     def snapshot(self) -> dict:
         def hist_mean(hists):

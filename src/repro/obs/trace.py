@@ -1,29 +1,36 @@
 """Per-request span tracing in Chrome trace-event JSON (Perfetto-loadable).
 
 The engine owns one :class:`SpanTracer` per run (via
-``Telemetry.tracer``).  Spans are *complete* events ("ph": "X") recorded
-after the fact from the engine's existing ``t0``/``t1`` monotonic stamps
-— no context managers on the hot path, one dict append per span.  The
-track layout maps the serving model directly:
+``Telemetry.tracer``).  The engine's phase spans are not emitted here:
+they live in the telemetry's span store (:mod:`repro.obs.spans`), which
+the tracer exports as *complete* events ("ph": "X") when the trace is
+written, so each phase has one emit site.  The track layout maps the
+serving model directly:
 
-* ``tid 0`` ("engine") — batched phase steps: decode steps, spec
-  draft/verify/commit phases, with batch size / rung / gamma as args;
+* ``tid 0`` ("engine") — the store's spans: ``repro/step`` with
+  ``repro/admit``, each phase's ``prepare``/``launch``/``readback``/
+  ``emit``, spec draft/verify/rollback, ``python/gc``, with batch size
+  / rung / gamma as args;
 * ``tid request_id + 1`` ("req-<id>") — each request's timeline:
   ``submit`` → ``admit`` (slot) → ``prefix_lookup`` (matched length) →
-  per-chunk ``prefill_chunk`` spans → ``first_token`` → ``finish``
-  (reason), plus per-round ``rollback`` instants under spec decoding.
+  its ``repro/prefill_chunk`` spans and their ``first_token`` (the
+  store's spans that carry a request id) → ``first_token`` →
+  ``finish`` (reason), plus per-round ``rollback`` instants under spec
+  decoding.
 
 Counter events ("ph": "C") chart queue depth and slot occupancy as
 Perfetto counter tracks.  Timestamps are microseconds since the
 tracer's creation, taken from the shared monotonic clock
 (:mod:`repro.obs.clock`) so spans, events, and stats are mutually
-orderable.  Load the exported file at https://ui.perfetto.dev (legacy
+orderable; ``otherData.mono_origin_ns`` gives that origin, and a
+profiler capture's ``repro/clock`` annotation the same clock's reading
+on the capture's time line.  Load the exported file at https://ui.perfetto.dev (legacy
 JSON is auto-detected) or ``chrome://tracing``.
 """
 from __future__ import annotations
 
 import json
-from typing import Dict, Optional
+from typing import Dict, List, Optional
 
 from repro.obs import clock
 
@@ -40,8 +47,11 @@ class SpanTracer:
     tracing is off (the engine checks ``tracer is not None``)."""
 
     def __init__(self, origin: Optional[float] = None):
-        self.origin = clock.now() if origin is None else origin
+        self.origin_ns = clock.now_ns() if origin is None \
+            else round(origin * 1e9)
+        self.origin = self.origin_ns * 1e-9
         self.events = []
+        self.spans = None           # the span store it exports, if bound
         self._named: Dict[int, str] = {}
         self.thread_name(ENGINE_TID, "engine")
 
@@ -80,12 +90,34 @@ class SpanTracer:
                             "ts": self._ts(clock.now() if t is None else t),
                             "args": values})
 
+    def bind(self, spans) -> None:
+        """Export ``spans`` (a :class:`repro.obs.spans.SpanStore`)."""
+        self.spans = spans
+
+    def span_events(self) -> List[dict]:
+        """The bound store's spans as complete events: on the engine
+        track, or on the request's track when a span carries its id."""
+        out: List[dict] = []
+        if self.spans is None:
+            return out
+        for i, r in self.spans.records():
+            tid = ENGINE_TID if r.request_id is None else r.request_id + 1
+            out.append({"ph": "X", "name": r.name, "pid": TRACE_PID,
+                        "tid": tid, "ts": (r.start_ns - self.origin_ns) / 1e3,
+                        "dur": max(0.0, (r.end_ns - r.start_ns) / 1e3),
+                        "args": dict(r.args, span=i, parent=r.parent)})
+        return out
+
     # ------------------------------------------------------------------
     def __len__(self) -> int:
-        return len(self.events)
+        """Events an export holds: the tracer's own and the store's."""
+        n = len(self.events)
+        return n if self.spans is None else n + len(self.spans)
 
     def to_dict(self) -> dict:
-        return {"traceEvents": list(self.events), "displayTimeUnit": "ms"}
+        return {"traceEvents": list(self.events) + self.span_events(),
+                "displayTimeUnit": "ms",
+                "otherData": {"mono_origin_ns": self.origin_ns}}
 
     def export(self, path: str) -> None:
         with open(path, "w") as f:

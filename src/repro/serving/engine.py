@@ -64,8 +64,12 @@ precompiled by :meth:`Engine.warmup`.
 Telemetry: ``Engine(..., telemetry=repro.obs.Telemetry(...))`` arms
 per-request span tracing (Chrome trace JSON), the structured event log
 (rung switches with controller reasons, gamma changes, prefix
-evictions, KV rollbacks, compile/retrace records) and per-dispatch JAX
-profiler annotations.  Telemetry only *observes* host-side state —
+evictions, KV rollbacks, compile/retrace records) and the program's
+spans (``repro.obs.spans``), each a JAX profiler annotation: ``repro/step``
+around a whole step, ``repro/admit``, and for each phase its numpy
+``prepare``, then the phase span (``repro/decode``,
+``repro/prefill_chunk``) around its ``launch`` and ``readback``, the
+chunk's ``first_token``, and the ``emit`` of its tokens.  Telemetry only *observes* host-side state —
 tokens are bit-identical with it on or off — and the default
 ``NULL_TELEMETRY`` costs nothing: every emit site is an ``is not
 None`` check and ``annotate()`` returns a shared null context.
@@ -74,8 +78,8 @@ Quality probes: ``Telemetry(quality=QualityMonitor(...))`` additionally
 arms live sparsity-quality observability (``repro.obs.quality``) —
 sampled shadow dense probes (run *before* the real decode dispatch, so
 served tokens and KV stay bit-identical), online Eq. 6 reconstruction
-error vs the ladder's calibration baselines, saliency-drift events, and
-per-rung roofline counters captured at :meth:`Engine.warmup`.  Both
+error vs the ladder's calibration baselines and saliency-drift events.
+Both
 quality executables precompile at warmup
 (``probe_retraces_after_warmup`` stays 0), and with
 ``SLOConfig.quality_aware`` the controller reads the drift-pressure
@@ -567,9 +571,8 @@ class Engine:
                                     self.ecfg.max_len - 1)
         if self.obs.quality is not None:
             # builds + precompiles the shadow-probe and reconstruction
-            # executables and AOT-captures per-rung roofline counters —
-            # before the retrace baseline below, so those compiles count
-            # as warmup, and live probing never traces
+            # executables — before the retrace baseline below, so those
+            # compiles count as warmup, and live probing never traces
             self.obs.quality.attach(self)
         self._warm_traces = (
             self._decode_traces, self._chunk_traces,
@@ -725,23 +728,26 @@ class Engine:
     def step(self) -> str:
         """Admit (expiring, resuming and preempting as the scheduler
         config allows), then run one scheduler-chosen phase step."""
-        self._admit()
-        self.stats.sample(self.scheduler.queue_depth, self.pool.num_occupied)
-        if self.obs.tracer is not None:
-            self.obs.tracer.counter(
-                "engine_load", queue_depth=self.scheduler.queue_depth,
-                occupancy=self.pool.num_occupied)
-        action = self.scheduler.next_action()
-        if action == "prefill":
-            if self.prefill_strategy == "chunked":
-                self._prefill_chunk(self.scheduler.prefill_head())
-            else:
-                self._prefill_whole(self.scheduler.prefill_group())
-        elif action == "decode":
-            if self.spec_decoder is not None:
-                self.spec_decoder.step()
-            else:
-                self._decode_step()
+        with self.obs.annotate("repro/step"):
+            with self.obs.annotate("repro/admit"):
+                self._admit()
+            self.stats.sample(self.scheduler.queue_depth,
+                              self.pool.num_occupied)
+            if self.obs.tracer is not None:
+                self.obs.tracer.counter(
+                    "engine_load", queue_depth=self.scheduler.queue_depth,
+                    occupancy=self.pool.num_occupied)
+            action = self.scheduler.next_action()
+            if action == "prefill":
+                if self.prefill_strategy == "chunked":
+                    self._prefill_chunk(self.scheduler.prefill_head())
+                else:
+                    self._prefill_whole(self.scheduler.prefill_group())
+            elif action == "decode":
+                if self.spec_decoder is not None:
+                    self.spec_decoder.step()
+                else:
+                    self._decode_step()
         return action
 
     def run(self) -> Dict[int, List[int]]:
@@ -913,31 +919,32 @@ class Engine:
     def _prefill_chunk(self, rs: RequestState) -> None:
         C = self.ecfg.prefill_chunk
         req = rs.request
+        rid = req.request_id
         off = rs.next_offset
         real = min(C, req.prompt_len - off)
-        chunk = np.zeros((1, C), np.int32)
-        chunk[0, :real] = req.prompt[off:off + real]
-        weights = np.zeros((C,), np.float32)
-        weights[:real] = 1.0
-        policy = self._phase_policy(off, req.prompt_len)
+        with self.obs.annotate("repro/prefill_chunk/prepare"):
+            chunk = np.zeros((1, C), np.int32)
+            chunk[0, :real] = req.prompt[off:off + real]
+            weights = np.zeros((C,), np.float32)
+            weights[:real] = 1.0
+            policy = self._phase_policy(off, req.prompt_len)
         t0 = self._now("prefill_chunk.t0")
-        with self.obs.annotate("repro/prefill_chunk"):
-            logits, self.pool.caches = self._cstep(
-                self.params, jnp.asarray(chunk),
-                jnp.full((1,), off, jnp.int32),
-                jnp.int32(rs.slot), self.pool.caches, self.sp,
-                jnp.asarray(weights), policy=policy)
-            logits.block_until_ready()
+        with self.obs.annotate("repro/prefill_chunk", rid, slot=rs.slot,
+                               offset=off, tokens=real, rung=self._rung):
+            with self.obs.annotate("repro/prefill_chunk/launch"):
+                logits, self.pool.caches = self._cstep(
+                    self.params, jnp.asarray(chunk),
+                    jnp.full((1,), off, jnp.int32),
+                    jnp.int32(rs.slot), self.pool.caches, self.sp,
+                    jnp.asarray(weights), policy=policy)
+            with self.obs.annotate("repro/prefill_chunk/readback"):
+                logits.block_until_ready()
         t1 = self._now("prefill_chunk.t1")
         dt = t1 - t0
         self.stats.prefill_time += dt
         self.stats.observe_prefill_step(dt)
         self.stats.prefill_chunks += 1
         self.stats.prefill_tokens += real
-        if self.obs.tracer is not None:
-            self.obs.tracer.complete(
-                "prefill_chunk", t0, t1, tid=req.request_id + 1,
-                slot=rs.slot, offset=off, tokens=real, rung=self._rung)
         rs.next_offset = off + real
         self.pool.lengths[rs.slot] = rs.next_offset
         if rs.done_prefill:
@@ -945,8 +952,10 @@ class Engine:
                 # release the admission pin and cache this prompt's
                 # prefix before decode can extend the slot
                 self.prefix_cache.publish(rs)
-            first = int(np.asarray(jnp.argmax(logits[0, real - 1])))
-            self._start_decode(rs, first)
+            with self.obs.annotate("repro/prefill_chunk/first_token", rid):
+                first = int(np.asarray(jnp.argmax(logits[0, real - 1])))
+            with self.obs.annotate("repro/prefill_chunk/emit"):
+                self._start_decode(rs, first)
 
     def _prefill_whole(self, group: List[RequestState]) -> None:
         P = group[0].request.prompt_len
@@ -957,7 +966,8 @@ class Engine:
         pd, ps, _ = self._rung_phases[self._rung]
         policy = ps if self.ecfg.prefill_dense_frac <= 0.0 else pd
         t0 = self._now("prefill_whole.t0")
-        with self.obs.annotate("repro/prefill_whole"):
+        with self.obs.annotate("repro/prefill_whole", prompt_len=P,
+                               batch=len(group), rung=self._rung):
             logits, caches = self._pstep(self.params, jnp.asarray(tokens),
                                          self.sp, policy=policy)
             logits.block_until_ready()
@@ -967,10 +977,6 @@ class Engine:
         self.stats.observe_prefill_step(dt)
         self.stats.prefill_chunks += 1
         self.stats.prefill_tokens += P * len(group)
-        if self.obs.tracer is not None:
-            self.obs.tracer.complete(
-                "prefill_whole", t0, t1, prompt_len=P, batch=len(group),
-                rung=self._rung)
         first = np.asarray(jnp.argmax(logits, axis=-1))
         for b, rs in enumerate(group):
             self.pool.insert(caches, b, rs.slot, P)
@@ -993,17 +999,18 @@ class Engine:
 
     def _decode_step(self) -> None:
         S = self.ecfg.max_slots
-        tokens = np.zeros((S,), np.int32)
-        # inactive slots write their garbage token at the scratch position
-        # (see pool_len above); their logits are ignored host-side and
-        # their saliency weight is zero
-        positions = np.full((S,), self.pool_len - 1, np.int32)
-        active = np.zeros((S,), np.float32)
         decoding = self.scheduler.decoding
-        for slot, rs in decoding.items():
-            tokens[slot] = rs.last_token
-            positions[slot] = rs.position
-            active[slot] = 1.0
+        with self.obs.annotate("repro/decode/prepare"):
+            tokens = np.zeros((S,), np.int32)
+            # inactive slots write their garbage token at the scratch
+            # position (see pool_len above); their logits are ignored
+            # host-side and their saliency weight is zero
+            positions = np.full((S,), self.pool_len - 1, np.int32)
+            active = np.zeros((S,), np.float32)
+            for slot, rs in decoding.items():
+                tokens[slot] = rs.last_token
+                positions[slot] = rs.position
+                active[slot] = 1.0
         _, _, dec_policy = self._rung_phases[self._rung]
         # shadow dense quality probe (sampled): runs *before* the real
         # decode so its K/V writes land exactly on the positions the
@@ -1015,20 +1022,28 @@ class Engine:
         if q is not None and q.should_probe():
             probe = q.run_probe(self, tokens, positions, active)
         t0 = self._now("decode.t0")
-        with self.obs.annotate("repro/decode"):
-            logits, self.pool.caches = self._dstep(
-                self.params, jnp.asarray(tokens), jnp.asarray(positions),
-                self.pool.caches, self.sp, jnp.asarray(active),
-                policy=dec_policy)
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with self.obs.annotate("repro/decode", active=len(decoding),
+                               rung=self._rung):
+            with self.obs.annotate("repro/decode/launch"):
+                logits, self.pool.caches = self._dstep(
+                    self.params, jnp.asarray(tokens),
+                    jnp.asarray(positions), self.pool.caches, self.sp,
+                    jnp.asarray(active), policy=dec_policy)
+            with self.obs.annotate("repro/decode/readback"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
         t1 = self._now("decode.t1")
         self.stats.decode_time += t1 - t0
         self.stats.observe_decode_step(t1 - t0)
         self.stats.decode_steps += 1
-        if self.obs.tracer is not None:
-            self.obs.tracer.complete(
-                "decode_step", t0, t1, active=len(decoding),
-                rung=self._rung)
+        with self.obs.annotate("repro/decode/emit"):
+            self._decode_emit(decoding, nxt, logits, active, probe, t1)
+
+    def _decode_emit(self, decoding, nxt, logits, active, probe,
+                     t1: float) -> None:
+        """A decode step's host side after its readback: each slot's
+        token (the client's callbacks), commit and finish, the quality
+        probe's observation and the controller."""
+        q = self.obs.quality
         gaps = []
         for slot, rs in list(decoding.items()):
             tok = int(nxt[slot])
@@ -1154,7 +1169,7 @@ class Engine:
             if self.obs.events is not None:
                 out["telemetry_events"] = self.obs.events.count
             if self.obs.tracer is not None:
-                out["telemetry_spans"] = len(self.obs.tracer.events)
+                out["telemetry_spans"] = len(self.obs.tracer)
         if self.obs.quality is not None and self.obs.quality.armed:
             out.update(self.obs.quality.snapshot())
         if self.obs.flight is not None:

@@ -196,44 +196,52 @@ class SpecDecoder:
 
         # inactive slots window into the pool's slack region (beyond every
         # reachable real position, like the plain decode scratch slot)
-        start = np.full((S,), eng.pool_len - (g + 1), np.int32)
-        cur = np.zeros((S,), np.int32)
-        active = np.zeros((S,), np.float32)
-        for slot, rs in decoding.items():
-            start[slot] = rs.position
-            cur[slot] = rs.last_token
-            active[slot] = 1.0
+        with eng.obs.annotate("repro/spec/prepare"):
+            start = np.full((S,), eng.pool_len - (g + 1), np.int32)
+            cur = np.zeros((S,), np.int32)
+            active = np.zeros((S,), np.float32)
+            for slot, rs in decoding.items():
+                start[slot] = rs.position
+                cur[slot] = rs.last_token
+                active[slot] = 1.0
 
         # --- draft: g sequential single-token steps, batched over slots --
         # the argmax chain stays on device (each draft feeds the next
         # without a host round-trip); one block per phase keeps the
         # draft/verify latency split honest without per-step syncs
         t0 = eng._now("spec.t0")
-        with eng.obs.annotate("repro/spec_draft"):
-            act = jnp.asarray(active)
-            toks = jnp.asarray(cur)
-            draft_cols = []
-            for i in range(g):
-                logits, eng.pool.caches = eng._dstep(
-                    params, toks, jnp.asarray(start + i),
-                    eng.pool.caches, draft_sp, act, policy=draft_pol)
-                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                draft_cols.append(toks)
-            drafts_dev = jnp.stack(draft_cols, axis=1)         # (S, g)
-            drafts_dev.block_until_ready()
+        with eng.obs.annotate("repro/spec_draft", gamma=g,
+                              drafter_rung=self.drafter_rung,
+                              active=len(decoding)):
+            with eng.obs.annotate("repro/spec_draft/launch"):
+                act = jnp.asarray(active)
+                toks = jnp.asarray(cur)
+                draft_cols = []
+                for i in range(g):
+                    logits, eng.pool.caches = eng._dstep(
+                        params, toks, jnp.asarray(start + i),
+                        eng.pool.caches, draft_sp, act, policy=draft_pol)
+                    toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    draft_cols.append(toks)
+                drafts_dev = jnp.stack(draft_cols, axis=1)     # (S, g)
+            with eng.obs.annotate("repro/spec_draft/readback"):
+                drafts_dev.block_until_ready()
         t1 = eng._now("spec.t1")
 
         # --- verify: one batched (g+1)-token forward ---------------------
-        with eng.obs.annotate("repro/spec_verify"):
-            vtokens = jnp.concatenate(
-                [jnp.asarray(cur)[:, None], drafts_dev], axis=1)
-            weights = np.repeat(active[:, None], g + 1, axis=1)
-            logits, eng.pool.caches = self._vstep(
-                params, vtokens, jnp.asarray(start),
-                eng.pool.caches, ver_sp, jnp.asarray(weights),
-                policy=ver_pol)
-            ver = np.asarray(jnp.argmax(logits, axis=-1))      # (S, g+1)
-            drafts = np.asarray(drafts_dev)
+        with eng.obs.annotate("repro/spec_verify", gamma=g,
+                              verifier_rung=self.verifier_rung):
+            with eng.obs.annotate("repro/spec_verify/launch"):
+                vtokens = jnp.concatenate(
+                    [jnp.asarray(cur)[:, None], drafts_dev], axis=1)
+                weights = np.repeat(active[:, None], g + 1, axis=1)
+                logits, eng.pool.caches = self._vstep(
+                    params, vtokens, jnp.asarray(start),
+                    eng.pool.caches, ver_sp, jnp.asarray(weights),
+                    policy=ver_pol)
+            with eng.obs.annotate("repro/spec_verify/readback"):
+                ver = np.asarray(jnp.argmax(logits, axis=-1))  # (S, g+1)
+                drafts = np.asarray(drafts_dev)
         t2 = eng._now("spec.t2")
 
         stats = eng.stats
@@ -243,12 +251,6 @@ class SpecDecoder:
         stats.observe_spec_draft(t1 - t0)
         stats.observe_spec_verify(t2 - t1)
         tracer = eng.obs.tracer
-        if tracer is not None:
-            tracer.complete("spec_draft", t0, t1, gamma=g,
-                            drafter_rung=self.drafter_rung,
-                            active=len(decoding))
-            tracer.complete("spec_verify", t1, t2, gamma=g,
-                            verifier_rung=self.verifier_rung)
 
         # --- accept, then one batched rollback, then emit ----------------
         accept_fracs = []
@@ -276,15 +278,13 @@ class SpecDecoder:
             eng.pool.commit(slot, g + 1)
             rollbacks[slot] = g + 1 - m
             commits[slot] = (rs, cand[:m], n_acc)
-        with eng.obs.annotate("repro/spec_rollback"):
+        with eng.obs.annotate("repro/spec_rollback",
+                              rollback_tokens=sum(rollbacks.values())):
             eng.pool.rollback_many(rollbacks)
         t3 = eng._now("spec.t3")
         # the round's decode cost includes the rollback dispatch — it is
         # real per-round work plain decode doesn't pay
         stats.decode_time += t3 - t0
-        if tracer is not None:
-            tracer.complete("spec_commit", t2, t3,
-                            rollback_tokens=sum(rollbacks.values()))
         events = eng.obs.events
 
         for slot, (rs, committed, n_acc) in commits.items():

@@ -363,18 +363,20 @@ def test_telemetry_parity_and_artifacts(model, tmp_path):
     assert flat["repro_decode_retraces_after_warmup_total"] == 0
 
     # trace: schema-valid, per-request lifecycle present on its track
+    # (closed first: the garbage collector's spans stop with the hook)
+    tel.close()
     path = tmp_path / "trace.json"
     tel.tracer.export(str(path))
     doc = json.loads(path.read_text())
-    assert validate_chrome_trace(doc) == len(tel.tracer.events)
+    assert validate_chrome_trace(doc) == len(tel.tracer)
+    assert doc["otherData"]["mono_origin_ns"] == tel.tracer.origin_ns
     for rid in range(len(prompts)):
         kinds = [e["name"] for e in doc["traceEvents"]
                  if e.get("tid") == rid + 1 and e["ph"] in ("i", "X")]
         assert kinds[0] == "submit" and "finish" in kinds
-        assert "prefill_chunk" in kinds and "first_token" in kinds
-    assert any(e["name"] == "decode_step" and e["ph"] == "X"
-               for e in doc["traceEvents"])
-    tel.close()
+        assert "repro/prefill_chunk" in kinds and "first_token" in kinds
+    assert any(e["name"] == "repro/decode" and e["ph"] == "X"
+               and e["tid"] == 0 for e in doc["traceEvents"])
 
     # snapshot v4+ fields (v5 added the admission/preemption block,
     # v6 the quality-probe block, v7 the flight block — both absent
@@ -383,7 +385,7 @@ def test_telemetry_parity_and_artifacts(model, tmp_path):
     assert snap["schema_version"] == 7
     assert "quality_probes" not in snap
     assert "flight_records" not in snap
-    assert snap["telemetry_spans"] == len(tel.tracer.events)
+    assert snap["telemetry_spans"] == len(tel.tracer)
     assert snap["tpot_p95_s"] >= snap["tpot_p50_s"]
     assert "tpot_p95_window_s" in snap
 
@@ -457,8 +459,10 @@ def test_spec_rollback_lands_in_event_log(model, ladder):
     assert ev["tokens"] == ev["gamma"] + 1 - ev["committed"] == 2
     assert len(out[0]) == 10
     # spec phases land as engine-track spans
-    names = {e["name"] for e in tel.tracer.events if e["ph"] == "X"}
-    assert {"spec_draft", "spec_verify", "spec_commit"} <= names
+    names = {e["name"] for e in tel.tracer.to_dict()["traceEvents"]
+             if e["ph"] == "X"}
+    assert {"repro/spec_draft", "repro/spec_verify",
+            "repro/spec_rollback"} <= names
 
 
 def test_prefix_eviction_lands_in_event_log(model):
