@@ -388,6 +388,9 @@ class PallasBlockSpecPass(GlobalPass):
         (5, 256, 509),       # prime output under tile/2
         (1, 128, 128),
     )
+    # W's leading dim: a 2-D weight (1) and a layer stack the kernel
+    # reads in place
+    _LAYERS = (1, 6)
 
     def run(self, repo_root: str) -> List[Finding]:
         import numpy as np
@@ -397,7 +400,7 @@ class PallasBlockSpecPass(GlobalPass):
 
         findings: List[Finding] = []
 
-        def check_plan(plan, idx_values, line_needle):
+        def check_plan(plan, prefetch_values, line_needle):
             line = _line_of(repo_root, self._REL, line_needle)
             for dim, tile, padded in plan.tiles:
                 if tile < 1 or padded % tile:
@@ -431,9 +434,9 @@ class PallasBlockSpecPass(GlobalPass):
                 corners = [(0, g // 2, g - 1) for g in plan.grid]
                 grid_points = itertools.product(*corners)
             for point in grid_points:
-                for idx in idx_values:
+                for prefetch in prefetch_values:
                     for b in plan.blocks:
-                        origin = b.index_map(*point, idx)
+                        origin = b.index_map(*point, *prefetch)
                         for d, (o, blk_d, pad_d) in enumerate(
                                 zip(origin, b.dims, b.padded)):
                             if o < 0 or (int(o) + 1) * blk_d > pad_d:
@@ -453,17 +456,22 @@ class PallasBlockSpecPass(GlobalPass):
         for B, n, m in self._SHAPES:
             blk = min(K.DEFAULT_BLK, n)
             nb_pad = (n + (-n % blk)) // blk
-            for kb in {1, max(1, nb_pad // 2), nb_pad}:
-                plan = K.shared_plan(B, n + (-n % blk), m, kb)
+            for kb, L in itertools.product({1, max(1, nb_pad // 2), nb_pad},
+                                           self._LAYERS):
+                # worst-case kept-block ids at the stack's first and last
+                # layer (the second scalar-prefetch operand)
+                lyrs = [np.zeros(1, np.int32), np.full(1, L - 1, np.int32)]
+                plan = K.shared_plan(B, n + (-n % blk), m, kb, L=L)
                 idxs = [np.zeros(kb, np.int32),
                         np.full(kb, nb_pad - 1, np.int32)]
-                check_plan(plan, idxs, "def shared_plan")
-                plan = K.per_seq_plan(B, n + (-n % blk), m, kb)
+                check_plan(plan, list(zip(idxs, lyrs)), "def shared_plan")
+                plan = K.per_seq_plan(B, n + (-n % blk), m, kb, L=L)
                 idxs = [np.zeros((B, kb), np.int32),
                         np.full((B, kb), nb_pad - 1, np.int32)]
-                check_plan(plan, idxs, "def per_seq_plan")
+                check_plan(plan, list(zip(idxs, lyrs)), "def per_seq_plan")
             sm = K.score_mask_plan(B, n + (-n % blk))
-            check_plan(sm, [np.zeros(2, np.float32)], "def score_mask_plan")
+            check_plan(sm, [(np.zeros(2, np.float32),)],
+                       "def score_mask_plan")
 
         # channel_plan contract: full-width blocks via padding, never
         # 1-wide fallback (the ops.wisparse_project side of PR 5's fix)

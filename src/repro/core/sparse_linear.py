@@ -24,7 +24,8 @@ migration notes).
 """
 from __future__ import annotations
 
-from typing import Optional
+import dataclasses
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,11 +34,27 @@ from repro.sparsity import CaptureSink, SparsityPolicy, VALID_BACKENDS
 
 __all__ = [
     "SparsityPolicy", "CaptureSink", "VALID_BACKENDS", "DENSE", "project",
-    "scores", "column_norms", "default_sp",
+    "LayerWeight", "scores", "column_norms", "default_sp",
 ]
 
 # the default execution when no policy is passed: plain dense matmuls
 DENSE = SparsityPolicy.dense()
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerWeight:
+    """One layer's weight as the scan over a stacked layer group holds
+    it: the whole ``(L, n_in, *out)`` stack and the traced layer index.
+    The ``pallas`` backend hands both to the kernel, which reads the
+    kept tiles of that layer straight from the stack; every other
+    consumer takes :meth:`sliced`, the layer's own ``(n_in, *out)``
+    weight, as the scan's ``xs`` would have handed it."""
+    stack: Any
+    index: Any
+
+    def sliced(self):
+        return jax.lax.dynamic_index_in_dim(self.stack, self.index, 0,
+                                            keepdims=False)
 
 
 # ---------------------------------------------------------------------------
@@ -94,6 +111,9 @@ def project(x, w, sp: Optional[dict] = None, row_parallel: bool = False, *,
     are already folded in by the model's scan driver; only role overrides
     remain to resolve here).  ``policy=None`` runs dense.
 
+    ``w`` is an array ``(n_in, *out)`` or a :class:`LayerWeight`, which
+    only the ``pallas`` backend reads in place.
+
     row_parallel statically marks weights whose *input* dim is
     model-sharded (o_proj/down_proj/out_proj).  The top-k gather backends
     then select a balanced per-shard channel budget so the gather stays
@@ -102,9 +122,11 @@ def project(x, w, sp: Optional[dict] = None, row_parallel: bool = False, *,
     """
     if policy is None:
         policy = DENSE
+    backend = policy.backend_at(role=role)
+    if isinstance(w, LayerWeight) and (sp is None or backend != "pallas"):
+        w = w.sliced()
     if policy.capture is not None:
         policy.capture.record(w, x)
-    backend = policy.backend_at(role=role)
     if sp is None or backend == "off":
         return _matmul(x, w)
     if backend == "mask":
@@ -125,7 +147,11 @@ def project(x, w, sp: Optional[dict] = None, row_parallel: bool = False, *,
                             token_weights=token_weights)
     if backend == "pallas":
         from repro.kernels import ops as kops
-        return kops.wisparse_project(x, w, sp, block=policy.block,
+        layer = None
+        if isinstance(w, LayerWeight):
+            w, layer = w.stack, w.index
+        return kops.wisparse_project(x, w, sp, layer=layer,
+                                     block=policy.block,
                                      k_frac=policy.k_max_frac,
                                      interpret=policy.interpret,
                                      token_weights=token_weights)

@@ -30,10 +30,14 @@ def channel_plan(n: int, block: int = 128):
     return blk, n_padded, n_padded // blk
 
 
-def wisparse_project(x, w, sp, *, block: int = 128, k_frac: float = 1.0,
-                     interpret=None, per_seq: bool = False,
-                     token_weights=None):
+def wisparse_project(x, w, sp, *, layer=None, block: int = 128,
+                     k_frac: float = 1.0, interpret=None,
+                     per_seq: bool = False, token_weights=None):
     """x: (..., n); w: (n, *out).  Returns x W with WiSparse block sparsity.
+
+    layer: with ``w`` an ``(L, n, m)`` layer stack, the (traced) layer
+    to read; the kernel DMAs that layer's kept tiles from the stack, so
+    the stack must tile without padding (:func:`K.reads_in_place`).
 
     interpret: Pallas interpret mode — ``None`` (default) auto-detects
     from the backend (compiled on TPU, interpreted elsewhere), matching
@@ -43,14 +47,23 @@ def wisparse_project(x, w, sp, *, block: int = 128, k_frac: float = 1.0,
     (the serving engine's active-slot / real-token mask, fused into the
     kernel); explicit None disables weighting."""
     interpret = K._resolve_interpret(interpret)
-    n = w.shape[0]
-    w2 = w.reshape(n, -1)
+    if layer is None:
+        n, out = w.shape[0], w.shape[1:]
+        w2 = w.reshape(n, -1)
+    else:
+        n, out = w.shape[1], w.shape[2:]
+        w2 = w
     lead = x.shape[:-1]
     xf = x.reshape(-1, n)
     blk, n_padded, _ = channel_plan(n, block)
     g = sp["g"]
     pad = n_padded - n
     if pad:
+        if layer is not None:
+            raise ValueError(
+                f"stacked weight {w.shape} does not tile its channels by "
+                f"{blk}: padding would copy the whole stack; pass the "
+                "layer's slice (see sparse_matmul.reads_in_place)")
         # keep full-width channel blocks on non-divisible dims by
         # zero-padding the channel axis (the old `while n % blk: blk -= 1`
         # fallback degraded to 1-wide blocks on prime dims, destroying
@@ -84,7 +97,9 @@ def wisparse_project(x, w, sp, *, block: int = 128, k_frac: float = 1.0,
 
     if per_seq:
         y = K.sparse_matmul_per_seq(xm, w2, jnp.tile(idx, (xf.shape[0], 1)),
-                                    blk=blk, interpret=interpret)
+                                    layer=layer, blk=blk,
+                                    interpret=interpret)
     else:
-        y = K.sparse_matmul_shared(xm, w2, idx, blk=blk, interpret=interpret)
-    return y.astype(x.dtype).reshape(lead + w.shape[1:])
+        y = K.sparse_matmul_shared(xm, w2, idx, layer=layer, blk=blk,
+                                   interpret=interpret)
+    return y.astype(x.dtype).reshape(lead + out)

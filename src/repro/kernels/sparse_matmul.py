@@ -15,6 +15,13 @@ Two variants:
   * per_seq — per-sequence block sets (the paper's per-token masks); W tiles
     are re-fetched per sequence, which is exactly the batching cost the
     paper's limitation section describes.
+
+Both take W as a 2-D weight or as an ``(L, n, m)`` layer stack with the
+layer to read (a second scalar-prefetch operand, which may be traced):
+inside the model's scan over a stacked layer group the kernel then DMAs
+its layer's kept tiles straight from the stack.  XLA cannot fuse a slice
+into a custom call, so a per-layer slice handed to the kernel would be
+written out in full before every call.
 """
 from __future__ import annotations
 
@@ -102,12 +109,15 @@ class KernelPlan:
         return DOUBLE_BUFFER * sum(b.block_bytes for b in self.blocks)
 
 
-def shared_plan(B: int, n: int, m: int, kb: int, *,
+def shared_plan(B: int, n: int, m: int, kb: int, *, L: int = 1,
                 blk: int = DEFAULT_BLK, mt: int = DEFAULT_MT,
                 bt: int = DEFAULT_BT, x_bytes: int = 4,
                 w_bytes: int = 4) -> KernelPlan:
     """Launch plan for :func:`sparse_matmul_shared` (also its single
-    source of geometry truth — the kernel reads tiles/grid from here)."""
+    source of geometry truth — the kernel reads tiles/grid from here).
+    W launches as the ``(L, n, m)`` layer stack with the layer dim
+    squeezed from the block; its index map reads the layer from the
+    second scalar-prefetch operand (a 2-D weight is the ``L = 1`` case)."""
     blk = min(blk, n)
     assert n % blk == 0, (n, blk)
     mt = _fit_tile(m, mt, LANE)
@@ -119,24 +129,26 @@ def shared_plan(B: int, n: int, m: int, kb: int, *,
         kernel="sparse_matmul_shared", grid=grid,
         inputs=(
             BlockPlan("x", (bt, blk), (Bp, n),
-                      lambda b, j, i, idx: (b, idx[i]), x_bytes),
-            BlockPlan("w", (blk, mt), (n, mp),
-                      lambda b, j, i, idx: (idx[i], j), w_bytes),
+                      lambda b, j, i, idx, lyr: (b, idx[i]), x_bytes),
+            BlockPlan("w", (None, blk, mt), (L, n, mp),
+                      lambda b, j, i, idx, lyr: (lyr[0], idx[i], j),
+                      w_bytes),
         ),
         outputs=(
             BlockPlan("y", (bt, mt), (Bp, mp),
-                      lambda b, j, i, idx: (b, j), 4),
+                      lambda b, j, i, idx, lyr: (b, j), 4),
         ),
         tiles=(("B", bt, Bp), ("m", mt, mp), ("n", blk, n)))
 
 
-def per_seq_plan(B: int, n: int, m: int, kb: int, *,
+def per_seq_plan(B: int, n: int, m: int, kb: int, *, L: int = 1,
                  blk: int = DEFAULT_BLK, mt: int = DEFAULT_MT,
                  x_bytes: int = 4, w_bytes: int = 4) -> KernelPlan:
     """Launch plan for :func:`sparse_matmul_per_seq`.  x and y launch
     as ``(B, 1, dim)`` with the batch dim squeezed (``None``) from the
     block, so each sequence's ``(1, blk)`` row block spans a full
-    unit dim instead of a 1-row slice of a B-row array."""
+    unit dim instead of a 1-row slice of a B-row array.  W launches as
+    in :func:`shared_plan`."""
     blk = min(blk, n)
     assert n % blk == 0
     mt = _fit_tile(m, mt, LANE)
@@ -146,13 +158,14 @@ def per_seq_plan(B: int, n: int, m: int, kb: int, *,
         kernel="sparse_matmul_per_seq", grid=grid,
         inputs=(
             BlockPlan("x", (None, 1, blk), (B, 1, n),
-                      lambda b, j, i, idx: (b, 0, idx[b, i]), x_bytes),
-            BlockPlan("w", (blk, mt), (n, mp),
-                      lambda b, j, i, idx: (idx[b, i], j), w_bytes),
+                      lambda b, j, i, idx, lyr: (b, 0, idx[b, i]), x_bytes),
+            BlockPlan("w", (None, blk, mt), (L, n, mp),
+                      lambda b, j, i, idx, lyr: (lyr[0], idx[b, i], j),
+                      w_bytes),
         ),
         outputs=(
             BlockPlan("y", (None, 1, mt), (B, 1, mp),
-                      lambda b, j, i, idx: (b, 0, j), 4),
+                      lambda b, j, i, idx, lyr: (b, 0, j), 4),
         ),
         tiles=(("m", mt, mp), ("n", blk, n)))
 
@@ -230,7 +243,37 @@ def _pad_dim(a, axis: int, tile: int):
     return a, size + pad
 
 
-def _acc_kernel(idx_ref, x_ref, w_ref, o_ref):
+def reads_in_place(n: int, m: int, *, blk: int = DEFAULT_BLK,
+                   mt: int = DEFAULT_MT) -> bool:
+    """Whether an ``(L, n, m)`` layer stack launches as it is: the
+    channel block divides ``n`` and the output tile divides ``m``.
+    Otherwise the plan pads W, and padding a stack copies every layer,
+    so the caller hands the kernel one layer's slice instead."""
+    return n % min(blk, n) == 0 and m % _fit_tile(m, mt, LANE) == 0
+
+
+def _layer_operands(w, layer, mt: int):
+    """W as the ``(L, n, mp)`` stack the plans launch over, and the
+    layer as the int32 ``(1,)`` scalar-prefetch operand.  A 2-D weight
+    is the stack of one at layer 0 (a free reshape), and only it may be
+    padded up to the output tile."""
+    if w.ndim == 2:
+        if layer is not None:
+            raise ValueError("layer selects from an (L, n, m) stack; "
+                             f"got a 2-D weight {w.shape}")
+        w, _ = _pad_dim(w[None], 2, mt)
+        return w, jnp.zeros((1,), jnp.int32)
+    if layer is None:
+        raise ValueError(f"a stacked weight {w.shape} needs its layer")
+    if w.shape[2] % mt:
+        raise ValueError(
+            f"stacked weight {w.shape} does not tile its output dim by "
+            f"{mt}: padding would copy the whole stack; pass the layer's "
+            "slice (see reads_in_place)")
+    return w, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _acc_kernel(idx_ref, layer_ref, x_ref, w_ref, o_ref):
     """One (batch-tile, out-tile) x kept-block accumulation step."""
     i = pl.program_id(2)
 
@@ -242,30 +285,36 @@ def _acc_kernel(idx_ref, x_ref, w_ref, o_ref):
                           preferred_element_type=jnp.float32)
 
 
-def sparse_matmul_shared(x, w, block_idx, *, blk: int = DEFAULT_BLK,
-                         mt: int = DEFAULT_MT, bt: int = DEFAULT_BT,
+def sparse_matmul_shared(x, w, block_idx, *, layer=None,
+                         blk: int = DEFAULT_BLK, mt: int = DEFAULT_MT,
+                         bt: int = DEFAULT_BT,
                          interpret: Optional[bool] = None):
     """y[b, :] = sum_{kept blocks i} x[b, blk_i] @ w[blk_i, :].
 
-    x: (B, n) already per-channel masked; w: (n, m); block_idx: (kb,) int32
+    x: (B, n) already per-channel masked; w: (n, m), or an (L, n, m)
+    layer stack with ``layer`` (int32 scalar, may be traced) naming the
+    layer to read — its kept tiles are DMA'd straight from the stack, so
+    no slice of the stack is ever materialised; block_idx: (kb,) int32
     kept channel-block ids (entries may repeat-pad with 0 iff the padded
-    lanes of x were zeroed).  Returns (B, m) float32.
+    lanes of x were zeroed).  Returns (B, m) float32, bit-identical to
+    the call on ``w[layer]``.
 
     Tiles shrink only to a clean divisor in [tile/2, tile]; otherwise
     the dim is zero-padded up to a tile multiple and the result sliced
     back — full-width MXU tiles regardless of shape.  (The old fallback
     shrank the tile until it divided, which silently degraded to 1-wide
-    tiles on prime dims.)
+    tiles on prime dims.)  A stack is never padded: see
+    :func:`reads_in_place`.
     """
     interpret = _resolve_interpret(interpret)
     B, n = x.shape
-    m = w.shape[1]
+    L, m = (1, w.shape[1]) if w.ndim == 2 else (w.shape[0], w.shape[2])
     kb = block_idx.shape[0]
-    plan = shared_plan(B, n, m, kb, blk=min(blk, n), mt=mt, bt=bt,
+    plan = shared_plan(B, n, m, kb, L=L, blk=min(blk, n), mt=mt, bt=bt,
                        x_bytes=x.dtype.itemsize, w_bytes=w.dtype.itemsize)
     (_, bt, Bp), (_, mt, mp), (_, blk, _) = plan.tiles
     x, _ = _pad_dim(x, 0, bt)
-    w, _ = _pad_dim(w, 1, mt)
+    w, layer = _layer_operands(w, layer, mt)
 
     xs, ws = plan.inputs
     (ys,) = plan.outputs
@@ -273,7 +322,7 @@ def sparse_matmul_shared(x, w, block_idx, *, blk: int = DEFAULT_BLK,
         y = pl.pallas_call(
             _acc_kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=2,
                 grid=plan.grid,
                 in_specs=[
                     pl.BlockSpec(xs.block, xs.index_map),
@@ -284,27 +333,28 @@ def sparse_matmul_shared(x, w, block_idx, *, blk: int = DEFAULT_BLK,
             out_shape=jax.ShapeDtypeStruct(ys.padded, jnp.float32),
             interpret=interpret,
             name=SHARED_NAME,
-        )(block_idx, x, w)
+        )(block_idx, layer, x, w)
     return y[:B, :m] if (Bp, mp) != (B, m) else y
 
 
-def sparse_matmul_per_seq(x, w, block_idx, *, blk: int = DEFAULT_BLK,
-                          mt: int = DEFAULT_MT,
+def sparse_matmul_per_seq(x, w, block_idx, *, layer=None,
+                          blk: int = DEFAULT_BLK, mt: int = DEFAULT_MT,
                           interpret: Optional[bool] = None):
     """Per-sequence kept-block sets (paper's per-token masks).
 
-    x: (B, n) masked; w: (n, m); block_idx: (B, kb) int32.  Returns (B, m).
-    Non-divisible output dims shrink to a clean divisor tile or pad
-    (see sparse_matmul_shared).
+    x: (B, n) masked; w: (n, m), or an (L, n, m) stack read at ``layer``
+    as in :func:`sparse_matmul_shared`; block_idx: (B, kb) int32.
+    Returns (B, m).  Non-divisible output dims shrink to a clean
+    divisor tile or pad (see sparse_matmul_shared).
     """
     interpret = _resolve_interpret(interpret)
     B, n = x.shape
-    m = w.shape[1]
+    L, m = (1, w.shape[1]) if w.ndim == 2 else (w.shape[0], w.shape[2])
     kb = block_idx.shape[1]
-    plan = per_seq_plan(B, n, m, kb, blk=min(blk, n), mt=mt,
+    plan = per_seq_plan(B, n, m, kb, L=L, blk=min(blk, n), mt=mt,
                         x_bytes=x.dtype.itemsize, w_bytes=w.dtype.itemsize)
     (_, mt, mp), (_, blk, _) = plan.tiles
-    w, _ = _pad_dim(w, 1, mt)
+    w, layer = _layer_operands(w, layer, mt)
 
     xs, ws = plan.inputs
     (ys,) = plan.outputs
@@ -312,7 +362,7 @@ def sparse_matmul_per_seq(x, w, block_idx, *, blk: int = DEFAULT_BLK,
         y = pl.pallas_call(
             _acc_kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
+                num_scalar_prefetch=2,
                 grid=plan.grid,
                 in_specs=[
                     pl.BlockSpec(xs.block, xs.index_map),
@@ -323,7 +373,7 @@ def sparse_matmul_per_seq(x, w, block_idx, *, blk: int = DEFAULT_BLK,
             out_shape=jax.ShapeDtypeStruct(ys.padded, jnp.float32),
             interpret=interpret,
             name=PER_SEQ_NAME,
-        )(block_idx, x[:, None], w)[:, 0]
+        )(block_idx, layer, x[:, None], w)[:, 0]
     return y[:, :m] if mp != m else y
 
 

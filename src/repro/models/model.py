@@ -18,12 +18,16 @@ on the forward path reads ambient thread-local state.
 """
 from __future__ import annotations
 
+import functools
+import operator
+
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core import sparse_linear
 from repro.distributed.sharding import constrain
+from repro.kernels.sparse_matmul import reads_in_place
 from repro.models import attention as attn_lib
 from repro.models.layers import apply_rope, dense, rmsnorm, rope_angles, softcap
 from repro.models.mlp import mlp_apply, mlp_schema
@@ -356,6 +360,88 @@ def _rep_backends(policy, depth0: int, plen: int, reps: int):
                   for j in range(plen)) for r in range(reps)]
 
 
+def _segments(policy, depth0: int, plen: int, reps: int):
+    """``(r0, r1, jpols)`` per scan segment of one stacked group: the
+    contiguous reps of equal backend signature and their per-pattern-
+    position policies."""
+    rb = _rep_backends(policy, depth0, plen, reps)
+    if rb is None:
+        return [(0, reps, (policy,) * plen)]
+    segs, s = [], 0
+    for r in range(1, reps + 1):
+        if r == reps or rb[r] != rb[s]:
+            jpols = tuple(policy.resolve_depth(depth0 + s * plen + j)
+                          for j in range(plen))
+            segs.append((s, r, jpols))
+            s = r
+    return segs
+
+
+def _sparse_paths(sp, path=()):
+    """Path of each sparse projection (an ``{"g", ...}`` leaf) in one
+    layer's sp tree; its ``/``-joined form is the projection's role."""
+    for k, v in sp.items():
+        if "g" in v:
+            yield path + (k,)
+        else:
+            yield from _sparse_paths(v, path + (k,))
+
+
+def _leaf(tree, path):
+    return functools.reduce(operator.getitem, path, tree)
+
+
+def _with_leaf(tree, path, value):
+    """Copy of nested dict ``tree`` with the leaf at ``path`` replaced."""
+    head, *rest = path
+    return {**tree, head: _with_leaf(tree[head], rest, value) if rest
+            else value}
+
+
+def _weight_feeds(gp, gsp, jpols):
+    """How one scan segment feeds its sparse projections' weights:
+    the param paths (``("l0", "mlp", "wo")``) whose stacked weight the
+    ``pallas`` kernel reads in place, and how many sparse projections per
+    layer take their weight as a slice through ``xs`` instead (expert
+    stacks, and stacks the kernel could only read padded)."""
+    in_place, sliced = [], 0
+    if gsp is None:
+        return in_place, sliced
+    for j, pol in enumerate(jpols):
+        for path in _sparse_paths(gsp.get(f"l{j}") or {}):
+            if pol is None or pol.backend_at(role="/".join(path)) != "pallas":
+                continue
+            w = _leaf(gp[f"l{j}"], path)
+            if w.ndim == 3 and reads_in_place(w.shape[1], w.shape[2],
+                                              blk=pol.block):
+                in_place.append((f"l{j}",) + path)
+            else:
+                sliced += 1
+    return in_place, sliced
+
+
+def sparse_weight_feeds(params, cfg: ModelConfig, sp=None, policy=None):
+    """``{"in_place": n, "sliced": m}``: how many sparse projections of a
+    decoder-stack forward under ``policy`` read their weight in place
+    from the layer stack, and how many through a per-layer slice.  The
+    decisions :func:`run_groups` takes, counted per layer; shapes alone
+    decide them, so it runs on tracers (the engine calls it while its
+    steps trace)."""
+    counts = {"in_place": 0, "sliced": 0}
+    if sp is None or policy is None:
+        return counts
+    depth = 0
+    for gi, (pattern, reps) in enumerate(cfg.layer_groups()):
+        plen = len(pattern)
+        for r0, r1, jpols in _segments(policy, depth, plen, reps):
+            in_place, sliced = _weight_feeds(params["groups"][gi], sp[gi],
+                                             jpols)
+            counts["in_place"] += len(in_place) * (r1 - r0)
+            counts["sliced"] += sliced * (r1 - r0)
+        depth += plen * reps
+    return counts
+
+
 def run_groups(groups, x, cfg: ModelConfig, patterns, *, mode="train",
                caches=None, positions=None, sp=None, enc_out=None,
                remat: str = "none", slot=None, policy=None,
@@ -377,36 +463,43 @@ def run_groups(groups, x, cfg: ModelConfig, patterns, *, mode="train",
         gsp = sp[gi] if sp is not None else None
         plen = len(pattern)
 
-        # NOTE (perf, measured in the decode dry-runs): carrying decode
-        # caches through the scan carry, or unrolling the layer loop over
-        # a stacked donated buffer, both force XLA to defensively copy the
-        # full stack per layer (10-600x memory-term regressions) — decode
-        # caches therefore flow through xs/ys like prefill, with
-        # update-only writes inside each per-layer slice.
-
-        rb = _rep_backends(policy, depth, plen, reps)
-        if rb is None:
-            segs = [(0, reps, (policy,) * plen)]
-        else:
-            segs, s = [], 0
-            for r in range(1, reps + 1):
-                if r == reps or rb[r] != rb[s]:
-                    jpols = tuple(policy.resolve_depth(depth + s * plen + j)
-                                  for j in range(plen))
-                    segs.append((s, r, jpols))
-                    s = r
+        # NOTE (perf): through ``xs`` flows each layer's slice of the
+        # caches, of the sp tree and of every weight a fused dot reads:
+        # XLA fuses that slice into the dot.  Decode caches flow there
+        # too, with update-only writes inside each per-layer slice:
+        # carrying them through the scan carry, or unrolling the layer
+        # loop over a stacked donated buffer, both force XLA to copy the
+        # full stack per layer (10-600x memory-term regressions in the
+        # decode dry-runs).  Not through ``xs``: the weight of a
+        # projection the ``pallas`` kernel runs.  A custom call cannot
+        # fuse a slice, so XLA would write the layer's whole dense
+        # weight to a new buffer before each call, twice its bytes for
+        # a kernel that reads the kept half (about 22 of the 130 ms of
+        # a DeepSeek-67B-width decode step on one TPU v5e).  The segment
+        # closes over that stack instead, and the kernel reads the
+        # layer's kept tiles from it at the scan's layer index
+        # (``LayerWeight``).
 
         seg_ys = []
-        for (r0, r1, jpols) in segs:
+        for (r0, r1, jpols) in _segments(policy, depth, plen, reps):
+            in_place, _ = _weight_feeds(gp, gsp, jpols)
+            rest = functools.reduce(
+                lambda t, path: _with_leaf(t, path, None), in_place, gp)
             if (r0, r1) == (0, reps):
-                xs = (gp, gc, gsp)
+                xs = (rest, gc, gsp)
             else:
                 xs = tuple(jax.tree_util.tree_map(
                     lambda a, lo=r0, hi=r1: a[lo:hi], t)
-                    for t in (gp, gc, gsp))
+                    for t in (rest, gc, gsp))
+            if in_place:
+                xs += (jnp.arange(r0, r1, dtype=jnp.int32),)
 
-            def body(xc, xs_in, pattern=pattern, jpols=jpols):
-                p_i, c_i, sp_i = xs_in
+            def body(xc, xs_in, pattern=pattern, jpols=jpols,
+                     in_place=in_place, gp=gp):
+                p_i, c_i, sp_i = xs_in[:3]
+                for path in in_place:
+                    p_i = _with_leaf(p_i, path, sparse_linear.LayerWeight(
+                        _leaf(gp, path), xs_in[3]))
                 ncs = []
                 for j, kind in enumerate(pattern):
                     cj = c_i[j] if c_i is not None else None

@@ -79,6 +79,23 @@ class Gauge:
         self.value = float(v)
 
 
+class GaugeFamily:
+    """Gauges of one name told apart by label values (one exposition
+    sample each, ``name{label="value",...}``)."""
+
+    __slots__ = ("labels", "values")
+
+    def __init__(self, labels: Sequence[str]):
+        self.labels = tuple(labels)
+        self.values: Dict[Tuple[str, ...], float] = {}
+
+    def set(self, label_values: Sequence[str], v: float) -> None:
+        if len(label_values) != len(self.labels):
+            raise ValueError(f"expected values for {self.labels}, "
+                             f"got {tuple(label_values)}")
+        self.values[tuple(label_values)] = float(v)
+
+
 class Histogram:
     """Fixed-bucket histogram with exact whole-run aggregates.
 
@@ -175,6 +192,10 @@ class MetricsRegistry:
     def gauge(self, name: str, help_: str = "") -> Gauge:
         return self._add(name, "gauge", help_, Gauge())
 
+    def gauge_family(self, name: str, labels: Sequence[str],
+                     help_: str = "") -> GaugeFamily:
+        return self._add(name, "gauge", help_, GaugeFamily(labels))
+
     def histogram(self, name: str, help_: str = "",
                   bounds: Optional[Sequence[float]] = None) -> Histogram:
         return self._add(name, "histogram", help_, Histogram(bounds))
@@ -196,7 +217,12 @@ class MetricsRegistry:
             if help_:
                 lines.append(f"# HELP {name} {help_}")
             lines.append(f"# TYPE {name} {kind}")
-            if kind in ("counter", "gauge"):
+            if isinstance(inst, GaugeFamily):
+                for key, v in inst.values.items():
+                    labels = ",".join(f'{k}="{x}"'
+                                      for k, x in zip(inst.labels, key))
+                    lines.append(f"{name}{{{labels}}} {self._fmt(v)}")
+            elif kind in ("counter", "gauge"):
                 lines.append(f"{name} {self._fmt(inst.value)}")
             else:
                 cum = inst.cumulative()
@@ -245,6 +271,16 @@ def engine_registry(engine) -> MetricsRegistry:
     if retr is not None:
         c("repro_decode_retraces_after_warmup_total", retr,
           "decode executable (re)traces since warmup (invariant: 0)")
+    feeds = getattr(engine, "sparse_weight_feeds", None)
+    if feeds:
+        fam = reg.gauge_family(
+            "repro_sparse_weight_feeds", ("program", "feed"),
+            "sparse projections per step program reading their weight "
+            "in place from the layer stack or through a per-layer slice "
+            "(recorded while the program traced)")
+        for program, counts in feeds.items():
+            for feed, n in counts.items():
+                fam.set((program, feed), n)
 
     reg.register_histogram("repro_tpot_seconds", s.tpot_hist,
                            "inter-token latency (whole-run, exact)")

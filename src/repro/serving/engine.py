@@ -97,6 +97,7 @@ import numpy as np
 from repro import obs
 from repro.configs.base import ModelConfig
 from repro.models import api
+from repro.models.model import sparse_weight_feeds
 from repro.obs import NULL_TELEMETRY, Telemetry
 from repro.serving.controller import AdaptiveController, SLOConfig
 from repro.serving.kv_pool import SlotKVPool
@@ -225,7 +226,8 @@ def make_engine_steps(cfg: ModelConfig, on_decode_trace=None,
 
     ``on_decode_trace`` / ``on_chunk_trace`` run inside the traced
     function body — i.e. only while XLA is (re)tracing — which is how
-    the engine counts retraces.
+    the engine counts retraces; each gets the traced program's
+    :func:`~repro.models.model.sparse_weight_feeds`.
 
     The pool caches are donated back into themselves each step (no copy
     on TPU; XLA falls back to copying where donation is unsupported).
@@ -238,14 +240,14 @@ def make_engine_steps(cfg: ModelConfig, on_decode_trace=None,
     def _decode(params, tokens, positions, caches, sp, active, *,
                 policy):
         if on_decode_trace is not None:
-            on_decode_trace()
+            on_decode_trace(sparse_weight_feeds(params, cfg, sp, policy))
         return slot_decode(params, tokens, positions, caches, sp,
                            active, policy=policy)
 
     def _chunk(params, tokens, offset, slot, caches, sp, weights, *,
                policy):
         if on_chunk_trace is not None:
-            on_chunk_trace()
+            on_chunk_trace(sparse_weight_feeds(params, cfg, sp, policy))
         return chunk_step(params, tokens, offset, slot, caches, sp,
                           weights, policy=policy)
 
@@ -395,6 +397,9 @@ class Engine:
         self._decode_traces = 0      # python-side retrace counter
         self._chunk_traces = 0
         self._warm_traces: Optional[int] = None
+        # per step program ("decode", "chunk", "verify"): how its sparse
+        # projections read their weights, recorded while it traces
+        self.sparse_weight_feeds: Dict[str, Dict[str, int]] = {}
 
         if ecfg.prefill_strategy == "auto":
             self.prefill_strategy = "chunked" if chunkable else "whole"
@@ -440,12 +445,14 @@ class Engine:
                 self.pool, ecfg.prefill_chunk, ecfg.prefix_cache_tokens,
                 stats_fn=lambda: self.stats, obs_fn=lambda: self.obs)
 
-        def _on_decode_trace():
+        def _on_decode_trace(feeds):
             self._decode_traces += 1        # runs only while tracing
+            self._record_feeds("decode", feeds)
             self._record_compile("decode")
 
-        def _on_chunk_trace():
+        def _on_chunk_trace(feeds):
             self._chunk_traces += 1
+            self._record_feeds("chunk", feeds)
             self._record_compile("prefill_chunk")
 
         self._dstep, self._cstep, self._pstep = make_engine_steps(
@@ -632,6 +639,15 @@ class Engine:
     # ------------------------------------------------------------------
     # telemetry plumbing
     # ------------------------------------------------------------------
+    def _record_feeds(self, program: str, feeds: Dict[str, int]) -> None:
+        """Called while ``program`` traces: keep how its sparse
+        projections read their weights — ``in_place`` from the layer
+        stack, or ``sliced`` through a per-layer copy — for
+        :attr:`sparse_weight_feeds`.  A trace with no sparse projection
+        (a dense phase) records nothing."""
+        if any(feeds.values()):
+            self.sparse_weight_feeds[program] = dict(feeds)
+
     def _record_compile(self, phase: str) -> None:
         """Called from inside the jitted wrappers — runs only while XLA
         is (re)tracing, so every emission is one compile record.  A

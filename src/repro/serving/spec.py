@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import api
+from repro.models.model import sparse_weight_feeds
 from repro.serving.controller import SpecController
 
 
@@ -107,13 +108,15 @@ def make_verify_jit(cfg, on_trace=None):
     donation configuration (policy static, pool caches donated) — the
     single construction site shared by :class:`SpecDecoder` and the
     ``repro.analysis`` jaxpr passes, so the lint lowers exactly what
-    serving runs.  ``on_trace`` runs only while XLA is (re)tracing."""
+    serving runs.  ``on_trace`` runs only while XLA is (re)tracing,
+    with the traced program's
+    :func:`~repro.models.model.sparse_weight_feeds`."""
     verify = api.make_verify_step(cfg)
 
     def _verify(params, tokens, positions, caches, sp, weights, *,
                 policy):
         if on_trace is not None:
-            on_trace()
+            on_trace(sparse_weight_feeds(params, cfg, sp, policy))
         return verify(params, tokens, positions, caches, sp, weights,
                       policy=policy)
 
@@ -140,8 +143,9 @@ class SpecDecoder:
         #                               mode's EWMA lives in the controller
         self._verify_traces = 0
 
-        def _on_trace():
+        def _on_trace(feeds):
             self._verify_traces += 1        # runs only while tracing
+            engine._record_feeds("verify", feeds)
 
         self._vstep = make_verify_jit(engine.cfg, on_trace=_on_trace)
         self.controller = None

@@ -292,6 +292,27 @@ def test_pallas_pass_catches_unaligned_blocks(monkeypatch):
         findings
 
 
+def test_pallas_pass_catches_layer_out_of_bounds(monkeypatch):
+    """A W index map that reads past the layer stack (here: the layer
+    after the one asked for) must be flagged: the pass evaluates every
+    plan at the stack's last layer."""
+    from repro.analysis.registry import global_passes
+    from repro.kernels import sparse_matmul as K
+    real_plan = K.shared_plan
+
+    def off_by_one(*a, **kw):
+        plan = real_plan(*a, **kw)
+        x, w = plan.inputs
+        w = dataclasses.replace(
+            w, index_map=lambda b, j, i, idx, lyr: (lyr[0] + 1, idx[i], j))
+        return dataclasses.replace(plan, inputs=(x, w))
+
+    monkeypatch.setattr(K, "shared_plan", off_by_one)
+    findings = global_passes()["pallas-blockspec"].run(REPO)
+    assert any(f.snippet == "sparse_matmul_shared/w" for f in findings), \
+        findings
+
+
 @pytest.mark.parametrize("block,padded,ok", [
     ((1, 1), (32, 1), False),           # old score_mask block scores
     ((1, 128), (16, 4096), False),      # old per-seq row block, B > 1
@@ -299,6 +320,8 @@ def test_pallas_pass_catches_unaligned_blocks(monkeypatch):
     ((8, 192), (8, 384), False),        # old lane tile of 192 for m=384
     ((1, 128), (1, 4096), True),        # unit dim spans the array
     ((None, 1, 128), (16, 1, 4096), True),
+    ((None, 128, 256), (6, 8192, 22016), True),   # W read from a stack
+    ((None, 128, 192), (6, 8192, 384), False),
     ((12, 64), (12, 64), True),         # whole array
     ((16, 128), (32, 4096), True),
     ((128,), (4096,), True),

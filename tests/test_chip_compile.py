@@ -14,6 +14,7 @@ around these compiles: an entry written without a chip cannot be read
 back here.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -159,3 +160,56 @@ def test_engine_chunk_step_compiles(one_chip, engine_args):
     hlo = cstep.lower(params, tokens, offset, slot, caches, sp, weights,
                       policy=_policy("pallas")).compile().as_text()
     assert KERNEL in hlo
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%(\S+) = (\w+\[[\d,]*\])\S* (\S+?)\(([^)]*)\)")
+
+
+def _layer_copies(hlo, stacks, layers):
+    """Fusions whose result is one layer's weight (shapes in ``layers``,
+    e.g. ``bf16[8192,22016]``) and that read a stacked weight (shapes in
+    ``stacks``): a layer's weight written out of its stack."""
+    shapes, hits = {}, []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, shape, op, args = m.groups()
+        shapes[name] = shape
+        if op == "fusion" and shape in layers and any(
+                shapes.get(a) in stacks
+                for a in re.findall(r"%([\w.\-]+)", args)):
+            hits.append(name)
+    return hits
+
+
+@pytest.mark.parametrize("feed", ["in_place", "sliced"])
+def test_pallas_decode_reads_weight_stacks_in_place(one_chip, monkeypatch,
+                                                    feed):
+    """The pallas decode step at DeepSeek-67B widths, two layers deep:
+    the sparse kernel reads each layer's kept tiles from the scan's
+    stacked weight, so no fusion writes one layer's gate, up or down
+    weight out of its stack.  The same step fed through per-layer
+    slices (the path a stack the kernel could only read padded takes)
+    does write them — the check can fail."""
+    from repro.models import model as M
+    if feed == "sliced":
+        monkeypatch.setattr(M, "reads_in_place", lambda *a, **kw: False)
+    S, T = 32, 2048
+    cfg = serving_config("deepseek_67b", layers=2)
+    params = _on(api.abstract_model(cfg)[0], one_chip)
+    caches = _on(P.abstract_params(api.cache_schema(cfg, S, T), cfg.dtype),
+                 one_chip)
+    sp = _on(abstract_sp(cfg)[0], one_chip)
+    tokens, positions, active = _on(
+        (jax.ShapeDtypeStruct((S,), jnp.int32),
+         jax.ShapeDtypeStruct((S,), jnp.int32),
+         jax.ShapeDtypeStruct((S,), jnp.float32)), one_chip)
+    dstep, _, _ = make_engine_steps(cfg)
+    hlo = dstep.lower(params, tokens, positions, caches, sp, active,
+                      policy=_policy("pallas")).compile().as_text()
+    assert K.SHARED_NAME in hlo
+    ffn = ("8192,22016", "22016,8192")
+    copies = _layer_copies(hlo, {f"bf16[2,{d}]" for d in ffn},
+                           {f"bf16[{u}{d}]" for d in ffn for u in ("", "1,")})
+    assert bool(copies) == (feed == "sliced"), copies

@@ -139,6 +139,50 @@ def test_wisparse_project_awkward_channel_dim(B, n, m, blk):
                                rtol=1e-5, atol=1e-5)
 
 
+# (B, n, m, blk) for an (L, n, m) layer stack: m a multiple of the 256
+# output tile; m under one tile (the tile is the whole dim); m that the
+# plan could only tile padded, so the stack must not be read in place
+STACKED = [
+    (4, 512, 512, 128),
+    (3, 256, 128, 128),
+    (2, 256, 300, 128),
+]
+
+
+@pytest.mark.parametrize("B,n,m,blk", STACKED)
+@pytest.mark.parametrize("kernel", ["shared", "per_seq"])
+@pytest.mark.parametrize("at", ["first", "last"])
+def test_stacked_weight_matches_layer_slice(B, n, m, blk, kernel, at):
+    """The kernel reading one layer's kept tiles straight from the
+    (L, n, m) stack returns, bit for bit, what the call on that layer's
+    own 2-D slice returns; a stack it could only read padded is refused
+    (padding would copy every layer), and its callers slice instead."""
+    L = 3
+    x, _, _ = _data(B, n, m, jnp.bfloat16)
+    ws = jnp.stack([_data(B, n, m, jnp.bfloat16, key=k)[1]
+                    for k in range(L)])
+    layer = 0 if at == "first" else L - 1
+    nb = n // blk
+    if kernel == "shared":
+        fn = K.sparse_matmul_shared
+        idx = jnp.arange(0, nb, 2, dtype=jnp.int32)
+    else:
+        fn = K.sparse_matmul_per_seq
+        idx = jnp.stack([(jnp.arange(max(nb // 2, 1)) + b) % nb
+                         for b in range(B)]).astype(jnp.int32)
+    want = fn(x, ws[layer], idx, blk=blk, interpret=True)
+    stacked = jax.jit(lambda x, w, i, lyr: fn(x, w, i, layer=lyr, blk=blk,
+                                              interpret=True))
+    if K.reads_in_place(n, m, blk=blk):
+        got = stacked(x, ws, idx, jnp.int32(layer))
+        assert got.shape == (B, m)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    else:
+        assert m == 300
+        with pytest.raises(ValueError, match="copy the whole stack"):
+            stacked(x, ws, idx, jnp.int32(layer))
+
+
 def test_interpret_auto_detects_backend():
     """interpret=None (the new default everywhere, including
     SparsityPolicy) resolves from the JAX backend: interpret-mode off

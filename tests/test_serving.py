@@ -174,3 +174,85 @@ def test_engine_stats_and_phase_times(model):
     for rs in eng.states.values():
         assert rs.first_token_time is not None
         assert rs.finish_time >= rs.first_token_time
+
+
+# ---------------------------------------------------------------------------
+# sparse projections read their weights in place from the layer stack
+# ---------------------------------------------------------------------------
+
+def _serve_warm(params, cfg, sp, policy, prompts, **kw):
+    eng = _engine(params, cfg, sp, policy=policy, **kw)
+    eng.warmup()
+    for p in prompts:
+        eng.submit(p, 6)
+    return eng, eng.run()
+
+
+def test_pallas_engine_reads_weights_in_place(model, monkeypatch):
+    """On the pallas rung every projection's kernel reads its layer's
+    tiles from the stacked weight (recorded while decode and the sparse
+    chunk trace), serves the tokens of the same engine fed through
+    per-layer slices, and never retraces; the feeds reach the metrics
+    exposition as a labelled gauge."""
+    from repro.models import model as M
+    from repro.obs.metrics import parse_exposition, validate_exposition
+    params, cfg = model
+    sp = default_sp_stacked(params, cfg, keep_frac=0.5)
+    policy = SparsityPolicy.uniform("pallas", k_max_frac=0.5)
+    prompts = list(_prompts(cfg, 3, 12, step=5))
+    n = 7 * cfg.num_layers              # q, k, v, o, gate, up, down
+    eng, out = _serve_warm(params, cfg, sp, policy, prompts,
+                           prefill_dense_frac=0.5)
+    assert eng.sparse_weight_feeds == {
+        "decode": {"in_place": n, "sliced": 0},
+        "chunk": {"in_place": n, "sliced": 0}}
+    assert eng.decode_retraces_after_warmup == 0
+    assert eng.chunk_retraces_after_warmup == 0
+    text = eng.metrics_exposition()
+    validate_exposition(text)
+    _, samples = parse_exposition(text)
+    feeds = {(lb["program"], lb["feed"]): v for name, lb, v in samples
+             if name == "repro_sparse_weight_feeds"}
+    assert feeds == {("decode", "in_place"): n, ("decode", "sliced"): 0,
+                     ("chunk", "in_place"): n, ("chunk", "sliced"): 0}
+
+    # the same engine with every stack fed through the scan's xs slices
+    monkeypatch.setattr(M, "reads_in_place", lambda *a, **kw: False)
+    sliced, sliced_out = _serve_warm(params, cfg, sp, policy, prompts,
+                                     prefill_dense_frac=0.5)
+    assert sliced.sparse_weight_feeds == {
+        "decode": {"in_place": 0, "sliced": n},
+        "chunk": {"in_place": 0, "sliced": n}}
+    assert sliced_out == out
+
+
+def test_dense_engine_programs_unchanged(model, monkeypatch):
+    """A dense-rung engine records no sparse weight feeds, and its decode
+    and chunk programs lower to the text of the xs-slicing path."""
+    from repro.models import model as M
+    from repro.serving.engine import make_engine_steps
+    params, cfg = model
+    sp = default_sp_stacked(params, cfg, keep_frac=0.5)
+    policy = SparsityPolicy.dense()
+    eng, out = _serve_warm(params, cfg, sp, policy,
+                           list(_prompts(cfg, 2, 12, step=9)))
+    assert eng.sparse_weight_feeds == {}
+    assert "repro_sparse_weight_feeds" not in eng.metrics_exposition()
+    assert eng.decode_retraces_after_warmup == 0
+    S, C = eng.ecfg.max_slots, eng.ecfg.prefill_chunk
+    zeros = jnp.zeros((S,), jnp.int32)
+
+    def program_text():
+        dstep, cstep, _ = make_engine_steps(cfg)
+        return (dstep.lower(params, zeros, zeros, eng.pool.caches, sp,
+                            jnp.ones((S,), jnp.float32),
+                            policy=policy).as_text(),
+                cstep.lower(params, jnp.zeros((1, C), jnp.int32),
+                            jnp.zeros((1,), jnp.int32), jnp.int32(0),
+                            eng.pool.caches, sp,
+                            jnp.ones((C,), jnp.float32),
+                            policy=policy).as_text())
+
+    now = program_text()
+    monkeypatch.setattr(M, "_weight_feeds", lambda gp, gsp, jpols: ([], 0))
+    assert program_text() == now

@@ -275,6 +275,28 @@ def test_spec_engine_token_parity(model, ladder):
     assert len(eng.states[3].token_rungs) == 6   # attributed to verifier
 
 
+def test_spec_pallas_drafter_reads_weights_in_place(model):
+    """A pallas drafter rung's decode program reads its sparse weights in
+    place from the layer stacks; the dense verifier's program records no
+    sparse feeds; neither retraces after warmup."""
+    params, cfg = model
+    ladder = PolicyLadder.uniform(params, cfg, (0.0, 0.5),
+                                  backend="pallas")
+    eng = _ladder_engine(model, ladder,
+                         spec=SpecConfig(gamma=2, drafter_rung=1))
+    for p in _prompts(cfg, 2, 12, step=3):
+        eng.submit(p, 4)
+    out = eng.run()
+    assert all(len(t) == 4 for t in out.values())
+    assert eng.stats.spec_rounds > 0
+    n = 7 * cfg.num_layers              # q, k, v, o, gate, up, down
+    in_place = {"in_place": n, "sliced": 0}
+    assert eng.sparse_weight_feeds == {"decode": in_place,
+                                       "chunk": in_place}
+    assert eng.decode_retraces_after_warmup == 0
+    assert eng.verify_retraces_after_warmup == 0
+
+
 def test_spec_gamma_switch_retrace_free(model, ladder):
     """Adaptive-range warmup precompiles every gamma: switching the draft
     length mid-serve neither retraces nor changes the output tokens."""
