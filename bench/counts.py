@@ -128,3 +128,38 @@ def sparse_matmul_work(s: Shape, rows: int, keep_frac: float,
                              k * m * w_bytes + rows * k * x_bytes
                              + rows * m * y_bytes)
     return Work(total.flops * s.layers, total.bytes * s.layers)
+
+
+def experts_hit(s: Shape, rows: int) -> float:
+    """Expected number of the published experts that ``rows`` tokens
+    route to at least once, each token choosing ``experts_per_tok`` of
+    them uniformly.  The harness cannot see the program's routing, so
+    the least work counts the experts a step is expected to touch."""
+    return s.experts * (1.0 - (1.0 - s.experts_per_tok / s.experts) ** rows)
+
+
+def expert_sparse_matmul_work(s: Shape, rows: int, keep_frac: float,
+                              block: int = 128, w_bytes: int = 2,
+                              x_bytes: int = 2,
+                              y_bytes: int = 2) -> Optional[Work]:
+    """The work one step's block-sparse expert projections need over
+    ``rows`` token rows at ``keep_frac`` of each projection's input
+    blocks: the kept weight blocks of each published expert expected to
+    be routed to (:func:`experts_hit`) read once, the kept part of x
+    read and y written for each of the rows x ``experts_per_tok``
+    assignments, and 2 FLOPs per kept multiply-add.  Pad experts count
+    for nothing.  None for a shape without experts."""
+    if not s.experts:
+        return None
+    hit = experts_hit(s, rows)
+    assigned = rows * s.experts_per_tok
+    total = Work(0.0, 0.0)
+    for role, n, m in projections(s):
+        if not role.startswith("mlp/"):
+            continue
+        nb = -(-n // block)
+        k = max(1, min(nb, round(nb * keep_frac))) * block
+        total = total + Work(2.0 * assigned * k * m,
+                             hit * k * m * w_bytes + assigned * k * x_bytes
+                             + assigned * m * y_bytes)
+    return Work(total.flops * s.layers, total.bytes * s.layers)

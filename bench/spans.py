@@ -6,8 +6,13 @@ The store stamps its spans on the monotonic clock, the trace on the
 profiler's.  The step spans ``repro/decode`` and ``repro/prefill_chunk``
 are both a stored span and a host annotation in the trace, entered in
 one ``with``: paired in order, the median of note start less record
-start is the offset between the clocks.  Pairs that spread over
-``MAX_SPREAD_NS`` mean the two do not line up, and nothing is read.
+start is the offset between the clocks.  The store's clock is read just
+before the annotation starts, so a pair whose host stood still between
+the two (a collection, a preempted thread) reads late by that stall
+alone, and leaves the median as it is.  The pairing stands where the
+``LINE_UP`` share of its pairs nearest the median lie within
+``MAX_SPREAD_NS`` of it; else the two do not line up, and nothing is
+read.
 
 Each idle gap of device 0 is then split at span boundaries.  An idle
 nanosecond goes to ``python/gc`` wherever a collection covers it, else
@@ -30,6 +35,7 @@ from bench import trace as TR
 
 ALIGN = ("repro/decode", "repro/prefill_chunk")
 MAX_SPREAD_NS = 100_000.0
+LINE_UP = 0.9           # share of the pairs that MAX_SPREAD_NS has to hold
 GC = "python/gc"
 GROUPS = ("engine_host", "dispatch", "gc", "client")
 # a step span's own time (outside its launch and readback) is dispatch
@@ -62,9 +68,10 @@ def store_records(run) -> Optional[list]:
 
 def offset(records: Sequence, notes: Sequence[TR.Span]
            ) -> Tuple[Optional[float], float]:
-    """(offset, spread) in ns: the median and the range of note start
-    less record start over the paired step spans; offset None where no
-    pairing lines up within ``MAX_SPREAD_NS``."""
+    """(offset, spread) in ns: the median of note start less record
+    start over the paired step spans, and the ``LINE_UP`` quantile of
+    each pair's distance from it; offset None where no pairing lines up
+    within ``MAX_SPREAD_NS``.  A stalled pair or two do not sink it."""
     recs = [r for _, r in records if r.name in ALIGN]
     ns = sorted((n for n in notes if n.name in ALIGN), key=lambda n: n.start)
     if not ns or len(recs) < len(ns):
@@ -80,12 +87,13 @@ def offset(records: Sequence, notes: Sequence[TR.Span]
         if not np.array_equal(rc[k:k + k_n], nc):
             continue
         offs = nst - rs[k:k + k_n]
-        spread = float(offs.max() - offs.min())
+        mid = float(statistics.median(offs.tolist()))
+        spread = float(np.quantile(np.abs(offs - mid), LINE_UP))
         if spread < best_spread:
-            best, best_spread = offs, spread
+            best, best_spread = mid, spread
     if best is None or best_spread > MAX_SPREAD_NS:
         return None, best_spread
-    return float(statistics.median(best.tolist())), best_spread
+    return best, best_spread
 
 
 def _innermost(spans: List[Tuple[float, float, str]]

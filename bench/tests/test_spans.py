@@ -8,6 +8,7 @@ import json
 import os
 import types
 
+import numpy as np
 import pytest
 
 from bench import run
@@ -161,6 +162,44 @@ def test_a_skewed_store_reads_nothing(tr):
     assert off is None and spread > SP.MAX_SPREAD_NS
     r = _run(tr, st)
     assert all(run.load_metric(n).read(r) is None for n in SHARES.values())
+
+
+def _step_pairs(stalls=(), n=140, lead=60, step_ns=41_800_000.0):
+    """A store of ``lead + n`` step spans (a chunk step one in five) and
+    the trace's notes of the last ``n``, each ``OFF`` plus 1-8 us after
+    its record; the notes at ``stalls`` (index: ns) start that much later,
+    as where the host stood still between the clock read and the note."""
+    rng = np.random.default_rng(0)
+    records, notes = [], []
+    for i in range(lead + n):
+        name = "repro/prefill_chunk" if i % 5 == 0 else "repro/decode"
+        a = i * step_ns + float(rng.uniform(0, 3_000_000))
+        records.append((i, SpanRecord(name, int(a), int(a + step_ns / 2),
+                                      -1, None, {})))
+        if i >= lead:
+            j = i - lead
+            late = float(rng.uniform(1_000, 8_000)) + stalls.get(j, 0)
+            notes.append(TR.Span(name, a + OFF + late, a + OFF + step_ns / 2))
+    return records, notes
+
+
+@pytest.mark.parametrize("stalls", [
+    {}, {70: 5_000_000}, {3: 180_000, 101: 100_000_000}],
+    ids=["none", "one-stall", "two-stalls"])
+def test_a_stalled_pair_leaves_the_offset(stalls):
+    records, notes = _step_pairs(stalls)
+    off, spread = SP.offset(records, notes)
+    # the median of the pairs, 1-8 us after OFF, wherever the stalls lie
+    assert off is not None and 1_000 <= off - OFF <= 8_000
+    assert spread < 8_000
+
+
+def test_a_pairing_that_strays_reads_nothing():
+    # a fifth of the notes stray by 0.2-3 ms: no clock offset fits them
+    records, notes = _step_pairs({j: 200_000 + j * 20_000
+                                  for j in range(0, 140, 5)})
+    off, spread = SP.offset(records, notes)
+    assert off is None and spread > SP.MAX_SPREAD_NS
 
 
 def test_a_program_without_a_store_reads_nothing(tr):
